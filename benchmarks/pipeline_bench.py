@@ -93,6 +93,9 @@ def _comm_audit(quick: bool) -> Dict:
     with tempfile.NamedTemporaryFile(suffix=".json") as tmp:
         cmd += ["--json", tmp.name]
         env = dict(os.environ)
+        # the audit lowers on a forced CPU mesh by design; without this
+        # the child would reach for the accelerator this process holds
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.join(os.path.dirname(__file__), "..", "src"),
              env.get("PYTHONPATH", "")])

@@ -12,8 +12,8 @@ grammar that both consumers build on:
   metadata (``input_output_alias`` / ``buffer_donor``), entry-parameter
   usage, and nested-tuple result shapes.
 
-Parsing conventions (all verified against live ``compiled.as_text()``
-per-device modules from the CPU backend, jax 0.4.x):
+Parsing conventions (all checked against live ``compiled.as_text()``
+per-device modules from the CPU backend by ``tests/test_analysis.py``):
 
 * a rank-0 shape ``f32[]`` is ONE element (4 bytes) — not zero;
 * tuple-shaped results ``(f32[2], s32[2])`` sum their members; tuples

@@ -15,7 +15,10 @@
   (the §Perf-winning formulation, TPU-native).
 
 ``ops`` holds the jit'd public wrappers; ``ref`` the pure-jnp oracles.
-On CPU the kernels run with ``interpret=True``; on TPU they compile.
+Each kernel is compiled when a program is lowered for TPU and interpreted
+on every other platform (``platform``); ``tests/test_tpu_compile.py``
+compiles the main-path kernels for a described v5e chip at FB15k-237
+widths.
 """
 from repro.kernels import ops, ref
 from repro.kernels.kge_score import EPILOGUES, NORM_EPS, apply_epilogue

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import run_pallas
+
 
 Q_BLOCK = 128   # query rows per tile
 C_BLOCK = 128   # candidate rows per tile
@@ -90,19 +92,22 @@ def kge_score(
         (q_bias.shape, c_bias.shape)
     if epilogue not in EPILOGUES:
         raise ValueError(f"unknown epilogue {epilogue!r}")
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return pl.pallas_call(
-        functools.partial(_kge_score_kernel, epilogue=epilogue),
-        grid=(b // Q_BLOCK, c // C_BLOCK),
-        in_specs=[
-            pl.BlockSpec((Q_BLOCK, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((C_BLOCK, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((Q_BLOCK, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, C_BLOCK), lambda i, j: (0, j)),
-            pl.BlockSpec((Q_BLOCK, C_BLOCK), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((Q_BLOCK, C_BLOCK), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
-        interpret=interpret,
-    )(q, candidates, q_bias, c_bias, bias)
+
+    def call(q, candidates, q_bias, c_bias, bias, *, interpret):
+        return pl.pallas_call(
+            functools.partial(_kge_score_kernel, epilogue=epilogue),
+            grid=(b // Q_BLOCK, c // C_BLOCK),
+            in_specs=[
+                pl.BlockSpec((Q_BLOCK, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((C_BLOCK, d), lambda i, j: (j, 0)),
+                pl.BlockSpec((Q_BLOCK, 1), lambda i, j: (i, 0)),
+                pl.BlockSpec((1, C_BLOCK), lambda i, j: (0, j)),
+                pl.BlockSpec((Q_BLOCK, C_BLOCK), lambda i, j: (i, j)),
+            ],
+            out_specs=pl.BlockSpec((Q_BLOCK, C_BLOCK), lambda i, j: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+            interpret=interpret,
+        )(q, candidates, q_bias, c_bias, bias)
+
+    return run_pallas(call, q, candidates, q_bias, c_bias, bias,
+                      interpret=interpret)

@@ -1,8 +1,10 @@
 """Jit-ready wrappers around the Pallas kernels: padding to block multiples,
 gathers that stay in XLA, and de-padding of results.
 
-These are the entry points the model layer uses; on CPU they run the kernels
-in interpret mode, on TPU they compile.
+These are the entry points the model layer uses.  Each kernel is compiled
+when lowered for TPU and interpreted elsewhere; where an op has a
+bit-identical XLA lowering, platforms other than TPU run that instead
+(``repro.kernels.platform``).
 """
 from __future__ import annotations
 
@@ -13,13 +15,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.kge_score import C_BLOCK, Q_BLOCK, kge_score
+from repro.kernels.platform import kernel_or_xla
 from repro.kernels.rgcn_message import (
     EDGE_BLOCK, VERTEX_BLOCK, basis_message, segment_sum_onehot,
 )
 from repro.kernels.sharded_gather import (
-    COT_BLOCK, ROW_BLOCK, fused_gather, scatter_add_onehot,
+    COT_BLOCK, ROW_BLOCK, fused_dequant_gather, fused_gather,
+    scatter_add_onehot,
 )
-from repro.kernels.topk import TOPK_Q_BLOCK, topk_scores
+from repro.kernels.topk import TOPK_C_BLOCK, TOPK_Q_BLOCK, topk_scores
 
 
 def _pad_to(x: jax.Array, n: int, axis: int = 0, fill=0) -> jax.Array:
@@ -165,22 +169,23 @@ def topk_padded(
     ``jax.lax.top_k`` — the same documented selection order (descending,
     lower index wins ties), with no arithmetic that could drift, so the
     two dispatches are bit-identical (``tests/test_serving.py`` asserts
-    kernel == ref == ``lax.top_k``)."""
+    kernel == ref == ``lax.top_k``).  On CPU the interpreter's per-grid
+    overhead would lose to XLA's native TopK."""
     b, c = scores.shape
     if not 1 <= k <= c:
         raise ValueError(f"k={k} outside [1, C={c}] — clamp before topk")
     scores = scores.astype(jnp.float32)
-    if use_kernel is None:
-        # mirror fused_sharded_gather: the kernel's iterative selection is
-        # VPU-friendly on TPU; on CPU the interpreter per-grid overhead
-        # loses to XLA's native sort-based TopK, which implements the
-        # identical order
-        use_kernel = jax.default_backend() == "tpu"
-    if not use_kernel:
-        return jax.lax.top_k(scores, k)
-    b_pad = _round_up(b, TOPK_Q_BLOCK)
-    vals, idx = topk_scores(_pad_to(scores, b_pad), k, interpret=interpret)
-    return vals[:b], idx[:b]
+
+    def kernel(s):
+        c_block = min(TOPK_C_BLOCK, _round_up(c, 128))
+        s = _pad_to(_pad_to(s, _round_up(b, TOPK_Q_BLOCK)),
+                    _round_up(c, c_block), axis=1)
+        vals, idx = topk_scores(s, k, num_cols=c, c_block=c_block,
+                                interpret=interpret)
+        return vals[:b], idx[:b]
+
+    return kernel_or_xla(kernel, lambda s: tuple(jax.lax.top_k(s, k)), scores,
+                         use_kernel=use_kernel)
 
 
 def merge_topk(
@@ -230,17 +235,14 @@ def _fused_sharded_gather_impl(table, local_ids, owned,
                                use_kernel: Optional[bool] = None):
     s, rows, d = table.shape
     flat, any_owned = flat_gather_plan(local_ids, owned, rows)
-    table_flat = table.reshape(s * rows, d)
-    if use_kernel is None:
-        # the per-row-DMA kernel wins on TPU; on CPU the interpreter's
-        # per-grid-step overhead would swamp the gather, so the production
-        # path is the IDENTICAL XLA lowering (one masked row gather —
-        # tests/test_kernels.py asserts kernel == XLA bit-for-bit)
-        use_kernel = jax.default_backend() == "tpu"
-    if use_kernel:
-        return fused_gather(table_flat, flat, any_owned,
-                            interpret=interpret)
-    return jnp.where(any_owned[:, None], table_flat[flat], 0.0)
+    # the per-row-DMA kernel on TPU; elsewhere the interpreter's
+    # per-grid-step overhead would swamp the gather, so the production
+    # path is the IDENTICAL XLA lowering (one masked row gather —
+    # tests/test_kernels.py asserts kernel == XLA bit-for-bit)
+    return kernel_or_xla(
+        functools.partial(fused_gather, interpret=interpret),
+        lambda t, f, o: jnp.where(o[:, None], t[f], 0.0),
+        table.reshape(s * rows, d), flat, any_owned, use_kernel=use_kernel)
 
 
 @jax.custom_vjp
@@ -279,15 +281,18 @@ def _fsg_bwd(res, g):
     s, rows, d = table.shape
     dtype = table.dtype
     flat, any_owned = flat_gather_plan(local_ids, owned, rows)
-    if jax.default_backend() == "tpu":
-        v = flat.shape[0]
-        v_pad = _round_up(v, COT_BLOCK)
+
+    def kernel(g, flat, any_owned):
+        v_pad = _round_up(flat.shape[0], COT_BLOCK)
         r_pad = _round_up(s * rows, ROW_BLOCK)
-        dt = scatter_add_onehot(
+        return scatter_add_onehot(
             _pad_to(g, v_pad), _pad_to(flat, v_pad),
             _pad_to(any_owned, v_pad, fill=False), r_pad)[:s * rows]
-    else:
-        dt = ref.sharded_scatter_add_ref(g, flat, any_owned, s * rows)
+
+    dt = kernel_or_xla(
+        kernel, functools.partial(ref.sharded_scatter_add_ref,
+                                  num_rows=s * rows),
+        g, flat, any_owned)
     return dt.reshape(s, rows, d).astype(dtype), None, None
 
 
@@ -323,17 +328,16 @@ def dequant_sharded_gather(
     computed in f32 either side of the gather."""
     s, rows, d = codes.shape
     flat, any_owned = flat_gather_plan(local_ids, owned, rows)
-    codes_flat = codes.reshape(s * rows, d)
-    scales_flat = scales.reshape(s * rows)
-    if use_kernel is None:
-        use_kernel = jax.default_backend() == "tpu"
-    if use_kernel:
-        from repro.kernels.sharded_gather import fused_dequant_gather
-        return fused_dequant_gather(codes_flat, scales_flat, flat,
-                                    any_owned, interpret=interpret)
-    rows_f32 = (codes_flat[flat].astype(jnp.float32)
-                * scales_flat[flat][:, None])
-    return jnp.where(any_owned[:, None], rows_f32, 0.0)
+
+    def xla(codes_flat, scales_flat, flat, any_owned):
+        rows_f32 = (codes_flat[flat].astype(jnp.float32)
+                    * scales_flat[flat][:, None])
+        return jnp.where(any_owned[:, None], rows_f32, 0.0)
+
+    return kernel_or_xla(
+        functools.partial(fused_dequant_gather, interpret=interpret), xla,
+        codes.reshape(s * rows, d), scales.reshape(s * rows), flat,
+        any_owned, use_kernel=use_kernel)
 
 
 def _qsg_impl(table, local_ids, owned):

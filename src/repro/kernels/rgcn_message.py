@@ -19,8 +19,8 @@ MXU matmuls from VMEM, so the op is re-thought as two tiled kernels:
    most (i, j) grid cells see an all-zero tile; a cheap in-kernel range test
    skips their compute (``pl.when``).
 
-Both kernels run under ``interpret=True`` on CPU (this container) and compile
-for TPU unchanged.  Oracles: ``repro.kernels.ref``.
+Both kernels are compiled when lowered for TPU and interpreted elsewhere
+(``repro.kernels.platform``).  Oracles: ``repro.kernels.ref``.
 """
 from __future__ import annotations
 
@@ -29,6 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import run_pallas
 
 
 EDGE_BLOCK = 128     # MXU-aligned edge tile
@@ -72,23 +74,25 @@ def basis_message(
     e, d_in = h_t.shape
     num_bases, _, d_out = bases.shape
     assert e % EDGE_BLOCK == 0, "pad edges to EDGE_BLOCK"
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    grid = (e // EDGE_BLOCK,)
     mask2d = edge_mask.astype(jnp.float32)[:, None]
-    return pl.pallas_call(
-        _basis_message_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((EDGE_BLOCK, d_in), lambda j: (j, 0)),
-            pl.BlockSpec((EDGE_BLOCK, num_bases), lambda j: (j, 0)),
-            pl.BlockSpec((EDGE_BLOCK, 1), lambda j: (j, 0)),
-            pl.BlockSpec((num_bases, d_in, d_out), lambda j: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((EDGE_BLOCK, d_out), lambda j: (j, 0)),
-        out_shape=jax.ShapeDtypeStruct((e, d_out), h_t.dtype),
-        interpret=interpret,
-    )(h_t, coef, mask2d, bases)
+
+    def call(h_t, coef, mask2d, bases, *, interpret):
+        return pl.pallas_call(
+            _basis_message_kernel,
+            grid=(e // EDGE_BLOCK,),
+            in_specs=[
+                pl.BlockSpec((EDGE_BLOCK, d_in), lambda j: (j, 0)),
+                pl.BlockSpec((EDGE_BLOCK, num_bases), lambda j: (j, 0)),
+                pl.BlockSpec((EDGE_BLOCK, 1), lambda j: (j, 0)),
+                pl.BlockSpec((num_bases, d_in, d_out),
+                             lambda j: (0, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((EDGE_BLOCK, d_out), lambda j: (j, 0)),
+            out_shape=jax.ShapeDtypeStruct((e, d_out), h_t.dtype),
+            interpret=interpret,
+        )(h_t, coef, mask2d, bases)
+
+    return run_pallas(call, h_t, coef, mask2d, bases, interpret=interpret)
 
 
 # ====================================================================== #
@@ -142,28 +146,31 @@ def segment_sum_onehot(
     Returns (agg (V, d), deg (V, 1)).  V padded to VERTEX_BLOCK by wrapper."""
     e, d = msg.shape
     assert e % EDGE_BLOCK == 0 and num_segments % VERTEX_BLOCK == 0
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     nv = num_segments // VERTEX_BLOCK
     ne = e // EDGE_BLOCK
     seg2d = seg.astype(jnp.int32)[:, None]
     mask2d = edge_mask.astype(jnp.int32)[:, None]
     kernel = functools.partial(_segment_sum_kernel, num_v_blocks=nv)
-    return pl.pallas_call(
-        kernel,
-        grid=(nv, ne),
-        in_specs=[
-            pl.BlockSpec((EDGE_BLOCK, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((EDGE_BLOCK, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((EDGE_BLOCK, 1), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((VERTEX_BLOCK, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((VERTEX_BLOCK, 1), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((num_segments, d), msg.dtype),
-            jax.ShapeDtypeStruct((num_segments, 1), msg.dtype),
-        ],
-        interpret=interpret,
-    )(msg, seg2d, mask2d)
+
+    def call(msg, seg2d, mask2d, *, interpret):
+        return pl.pallas_call(
+            kernel,
+            grid=(nv, ne),
+            in_specs=[
+                pl.BlockSpec((EDGE_BLOCK, d), lambda i, j: (j, 0)),
+                pl.BlockSpec((EDGE_BLOCK, 1), lambda i, j: (j, 0)),
+                pl.BlockSpec((EDGE_BLOCK, 1), lambda i, j: (j, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((VERTEX_BLOCK, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((VERTEX_BLOCK, 1), lambda i, j: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((num_segments, d), msg.dtype),
+                jax.ShapeDtypeStruct((num_segments, 1), msg.dtype),
+            ],
+            interpret=interpret,
+        )(msg, seg2d, mask2d)
+
+    agg, deg = run_pallas(call, msg, seg2d, mask2d, interpret=interpret)
+    return agg, deg

@@ -17,9 +17,9 @@ This module provides that collapsed op as Pallas kernels:
 
 * ``fused_gather`` — forward: one output row per grid step; the row index
   is a scalar-prefetch argument (``PrefetchScalarGridSpec``), so the block
-  index map DMAs exactly the owner's row from the stacked table and the
-  ownership mask is applied in-register.  No (S, V, d) intermediate, no
-  S-way elementwise mask, no reduction.
+  index map DMAs exactly the owner's row from the stacked table, and the
+  ownership mask, carried in the index's sign, is applied in-register.
+  No (S, V, d) intermediate, no S-way elementwise mask, no reduction.
 * ``fused_dequant_gather`` — the int8 variant: same grid, but the DMA'd
   row is an int8 code row plus its (1, 1) fp32 per-row scale, and the
   dequantize (``codes.astype(f32) · scale``) happens in-register — the
@@ -31,7 +31,8 @@ This module provides that collapsed op as Pallas kernels:
   pair build the 0/1 incidence tile and accumulate ``onehot @ g`` on the
   MXU, skipping tiles no cotangent row hits.
 
-Both run under ``interpret=True`` on CPU and compile for TPU unchanged.
+Each is compiled when lowered for TPU and interpreted elsewhere
+(``repro.kernels.platform``).
 Oracles: ``repro.kernels.ref.sharded_gather_ref`` (the original
 take→mask→sum chain) and ``ref.sharded_scatter_add_ref``.  The jit-ready
 entry point with the custom VJP (and the XLA lowering used on non-TPU
@@ -45,6 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import run_pallas
+
 
 ROW_BLOCK = 128   # table-row tile of the scatter-add kernel
 COT_BLOCK = 128   # cotangent-row tile of the scatter-add kernel
@@ -53,13 +56,33 @@ COT_BLOCK = 128   # cotangent-row tile of the scatter-add kernel
 # ====================================================================== #
 # Forward: fused gather + mask (+ the accumulate folded into flat ids)
 # ====================================================================== #
-def _fused_gather_kernel(flat_ref, mask_ref, table_ref, out_ref):
-    """One gathered row per grid step.  ``table_ref`` is the (1, d) row the
-    scalar-prefetched flat index selected via the block index map; a row no
-    shard owns (dedup-plan padding) is zeroed in-register — the fused
-    remnant of the old exchange mask."""
-    del flat_ref  # consumed by the index maps (scalar prefetch)
-    out_ref[...] = jnp.where(mask_ref[...] != 0, table_ref[...], 0.0)
+# One gathered row per grid step.  The TPU lowering tiles the last two
+# block dimensions by (8, 128) unless they span the whole array, so a
+# single-row block of an (R, d) table is refused.  The kernels therefore
+# view the table as (R, 1, d) and take a squeezed (None, 1, d) block: the
+# last two dimensions are then whole.  Ownership rides in the sign of the
+# scalar-prefetched row index (-1 = no shard owns the slot), so a row no
+# shard owns is zeroed in-register and its DMA reads row 0.
+def _signed_rows(flat_ids: jax.Array, any_owned: jax.Array) -> jax.Array:
+    return jnp.where(any_owned, flat_ids.astype(jnp.int32), -1)
+
+
+def _row_block(width: int) -> pl.BlockSpec:
+    """The owner's row of an (R, 1, width) operand, by prefetched index."""
+    return pl.BlockSpec((None, 1, width),
+                        lambda i, rows: (jnp.maximum(rows[i], 0), 0, 0))
+
+
+def _out_block(d: int) -> pl.BlockSpec:
+    return pl.BlockSpec((None, 1, d), lambda i, rows: (i, 0, 0))
+
+
+def _fused_gather_kernel(rows_ref, table_ref, out_ref):
+    """``table_ref`` is the (1, d) row the prefetched index selected; a
+    slot no shard owns (dedup-plan padding) is zeroed in-register — the
+    fused remnant of the old exchange mask."""
+    owned = rows_ref[pl.program_id(0)] >= 0
+    out_ref[...] = jnp.where(owned, table_ref[...], 0.0)
 
 
 def fused_gather(
@@ -72,39 +95,34 @@ def fused_gather(
     : 0`` — the collapsed form of the shard-local take → mask → sum chain
     (``ref.sharded_gather_ref``), one row DMA per output row."""
     v = flat_ids.shape[0]
-    d = table_flat.shape[1]
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(v,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, ids: (i, 0)),
-            pl.BlockSpec((1, d), lambda i, ids: (ids[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i, ids: (i, 0)),
-    )
-    return pl.pallas_call(
-        _fused_gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((v, d), table_flat.dtype),
-        interpret=interpret,
-    )(flat_ids.astype(jnp.int32),
-      any_owned.astype(jnp.int32).reshape(v, 1), table_flat)
+    r, d = table_flat.shape
+
+    def call(rows, table, *, interpret):
+        return pl.pallas_call(
+            _fused_gather_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(v,),
+                in_specs=[_row_block(d)], out_specs=_out_block(d)),
+            out_shape=jax.ShapeDtypeStruct((v, 1, d), table.dtype),
+            interpret=interpret,
+        )(rows, table)
+
+    out = run_pallas(call, _signed_rows(flat_ids, any_owned),
+                     table_flat.reshape(r, 1, d), interpret=interpret)
+    return out.reshape(v, d)
 
 
 # ====================================================================== #
 # Forward (int8): fused dequantize + gather + mask
 # ====================================================================== #
-def _fused_dequant_gather_kernel(flat_ref, mask_ref, codes_ref, scale_ref,
-                                 out_ref):
-    """int8 twin of ``_fused_gather_kernel``: the scalar-prefetched flat
-    index DMAs the owner's (1, d) int8 code row AND its (1, 1) fp32 scale;
-    the row is dequantized in-register (``codes.astype(f32) · scale``) —
-    the fp32 row never exists outside this tile."""
-    del flat_ref  # consumed by the index maps (scalar prefetch)
+def _fused_dequant_gather_kernel(rows_ref, codes_ref, scale_ref, out_ref):
+    """int8 twin of ``_fused_gather_kernel``: the prefetched index DMAs
+    the owner's (1, d) int8 code row AND its (1, 1) fp32 scale; the row
+    is dequantized in-register (``codes.astype(f32) · scale``) — the fp32
+    row never exists outside this tile."""
+    owned = rows_ref[pl.program_id(0)] >= 0
     row = codes_ref[...].astype(jnp.float32) * scale_ref[...]
-    out_ref[...] = jnp.where(mask_ref[...] != 0, row, 0.0)
+    out_ref[...] = jnp.where(owned, row, 0.0)
 
 
 def fused_dequant_gather(
@@ -121,26 +139,23 @@ def fused_dequant_gather(
     ``ref.dequant_gather_ref``."""
     v = flat_ids.shape[0]
     r, d = codes_flat.shape
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(v,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, ids: (i, 0)),
-            pl.BlockSpec((1, d), lambda i, ids: (ids[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, ids: (ids[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i, ids: (i, 0)),
-    )
-    return pl.pallas_call(
-        _fused_dequant_gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((v, d), jnp.float32),
-        interpret=interpret,
-    )(flat_ids.astype(jnp.int32),
-      any_owned.astype(jnp.int32).reshape(v, 1), codes_flat,
-      scales_flat.astype(jnp.float32).reshape(r, 1))
+
+    def call(rows, codes, scales, *, interpret):
+        return pl.pallas_call(
+            _fused_dequant_gather_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(v,),
+                in_specs=[_row_block(d), _row_block(1)],
+                out_specs=_out_block(d)),
+            out_shape=jax.ShapeDtypeStruct((v, 1, d), jnp.float32),
+            interpret=interpret,
+        )(rows, codes, scales)
+
+    out = run_pallas(call, _signed_rows(flat_ids, any_owned),
+                     codes_flat.reshape(r, 1, d),
+                     scales_flat.astype(jnp.float32).reshape(r, 1, 1),
+                     interpret=interpret)
+    return out.reshape(v, d)
 
 
 # ====================================================================== #
@@ -190,18 +205,20 @@ def scatter_add_onehot(
     v, d = g.shape
     assert v % COT_BLOCK == 0 and num_rows % ROW_BLOCK == 0, \
         "pad V/num_rows to tile multiples (ops wrapper)"
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return pl.pallas_call(
-        _scatter_add_kernel,
-        grid=(num_rows // ROW_BLOCK, v // COT_BLOCK),
-        in_specs=[
-            pl.BlockSpec((COT_BLOCK, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((COT_BLOCK, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((COT_BLOCK, 1), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((ROW_BLOCK, d), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((num_rows, d), jnp.float32),
-        interpret=interpret,
-    )(flat_ids.astype(jnp.int32)[:, None], g,
-      any_owned.astype(jnp.int32)[:, None])
+    def call(flat, g, owned, *, interpret):
+        return pl.pallas_call(
+            _scatter_add_kernel,
+            grid=(num_rows // ROW_BLOCK, v // COT_BLOCK),
+            in_specs=[
+                pl.BlockSpec((COT_BLOCK, 1), lambda i, j: (j, 0)),
+                pl.BlockSpec((COT_BLOCK, d), lambda i, j: (j, 0)),
+                pl.BlockSpec((COT_BLOCK, 1), lambda i, j: (j, 0)),
+            ],
+            out_specs=pl.BlockSpec((ROW_BLOCK, d), lambda i, j: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((num_rows, d), jnp.float32),
+            interpret=interpret,
+        )(flat, g, owned)
+
+    return run_pallas(call, flat_ids.astype(jnp.int32)[:, None], g,
+                      any_owned.astype(jnp.int32)[:, None],
+                      interpret=interpret)

@@ -18,10 +18,12 @@ scratch state persists across a tile's chunks.  Oracle:
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import run_pallas
 
 BH_BLOCK = 8        # batch·head rows per tile (sublane-aligned)
 
@@ -78,26 +80,18 @@ def wkv_chunked(r: jax.Array, k: jax.Array, v: jax.Array,
     multiple of BH_BLOCK and S of ``chunk`` (ops wrapper pads)."""
     bh, s, hd = r.shape
     assert bh % BH_BLOCK == 0 and s % chunk == 0, (bh, s)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    grid = (bh // BH_BLOCK, s // chunk)
     seq_spec = pl.BlockSpec((BH_BLOCK, chunk, hd), lambda i, j: (i, j, 0))
-    return pl.pallas_call(
-        _wkv_kernel,
-        grid=grid,
-        in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((BH_BLOCK, hd), lambda i, j: (i, 0))],
-        out_specs=seq_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, s, hd), r.dtype),
-        scratch_shapes=[pltpu_scratch((BH_BLOCK, hd, hd))],
-        interpret=interpret,
-    )(r, k, v, log_decay, u)
 
+    def call(r, k, v, log_decay, u, *, interpret):
+        return pl.pallas_call(
+            _wkv_kernel,
+            grid=(bh // BH_BLOCK, s // chunk),
+            in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
+                      pl.BlockSpec((BH_BLOCK, hd), lambda i, j: (i, 0))],
+            out_specs=seq_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, s, hd), r.dtype),
+            scratch_shapes=[pltpu.VMEM((BH_BLOCK, hd, hd), jnp.float32)],
+            interpret=interpret,
+        )(r, k, v, log_decay, u)
 
-def pltpu_scratch(shape):
-    """VMEM f32 scratch (portable across pallas versions)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:   # pragma: no cover - older API
-        return pl.VMEM(shape, jnp.float32)
+    return run_pallas(call, r, k, v, log_decay, u, interpret=interpret)

@@ -32,9 +32,7 @@ import numpy as np
 
 from repro.configs import ASSIGNED
 from repro.launch import specs as S
-from repro.launch.mesh import (
-    HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh,
-)
+from repro.launch.mesh import chip_peaks, make_production_mesh
 from repro.launch.steps import (
     make_prefill_step, make_serve_step, make_train_step,
 )
@@ -44,6 +42,10 @@ from repro.sharding.rules import (
     batch_shardings, cache_shardings, opt_state_shardings, param_shardings,
 )
 from repro.training.optimizer import adam
+
+# The chip the production meshes model: the roofline divides by ITS peaks
+# (the lowering itself runs on forced host devices).
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def lower_rgcn(mesh_kind: str, overrides: str = "") -> Dict:
@@ -115,16 +117,15 @@ def lower_rgcn(mesh_kind: str, overrides: str = "") -> Dict:
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # jax<=0.4 returns [dict]
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     hlo_text = compiled.as_text()
     coll_bytes, coll_stats = total_collective_bytes(hlo_text)
     parsed = analyze_hlo(hlo_text)
+    peaks = chip_peaks(TARGET_DEVICE_KIND)
     terms = {
-        "compute_s": parsed["flops"] / PEAK_FLOPS_BF16,
-        "memory_s": parsed["bytes"] / HBM_BW,
-        "collective_s": coll_bytes / ICI_BW,
+        "compute_s": parsed["flops"] / peaks["peak_flops_bf16"],
+        "memory_s": parsed["bytes"] / peaks["hbm_bw"],
+        "collective_s": coll_bytes / peaks["ici_bw"],
     }
     dominant = max(terms, key=terms.get)
     return {
@@ -203,8 +204,6 @@ def lower_one(arch_name: str, shape_name: str, mesh_kind: str,
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # jax<=0.4 returns [dict]
-        cost = cost[0] if cost else {}
     try:
         mem = compiled.memory_analysis()
         mem_rec = {
@@ -231,9 +230,10 @@ def lower_one(arch_name: str, shape_name: str, mesh_kind: str,
     mf = S.model_flops(cfg, shape)
 
     # roofline terms (seconds), per-device program numbers
-    t_compute = hlo_flops / PEAK_FLOPS_BF16
-    t_memory = hlo_bytes / HBM_BW
-    t_coll = coll_bytes / ICI_BW
+    peaks = chip_peaks(TARGET_DEVICE_KIND)
+    t_compute = hlo_flops / peaks["peak_flops_bf16"]
+    t_memory = hlo_bytes / peaks["hbm_bw"]
+    t_coll = coll_bytes / peaks["ici_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_coll}
     dominant = max(terms, key=terms.get)

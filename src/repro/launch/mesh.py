@@ -11,17 +11,10 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 explicit-sharding API; older jax has no AxisType
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes, devices) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes, devices=devices)
     return jax.make_mesh(
         shape, axes,
         axis_types=(AxisType.Auto,) * len(axes),
@@ -71,7 +64,24 @@ def fit_spmd_mesh(num_trainers: int, num_table_shards: int,
     return data, model
 
 
-# TPU v5e hardware constants used by the roofline (EXPERIMENTS.md §Roofline)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+# Published peaks of one chip, keyed by ``jax.Device.device_kind``
+# (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of interconnect over 4 links).  A kind that is not here is
+# an error, never a default.
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops_bf16": 197e12,   # FLOP/s per chip
+        "hbm_bw": 819e9,             # bytes/s per chip
+        "ici_bw": 50e9,              # bytes/s per link
+    },
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """The peak table of ``device_kind``; raises for a kind it lacks."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table for device kind {device_kind!r} "
+            f"(known: {sorted(CHIP_PEAKS)})") from None

@@ -111,7 +111,10 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import KGEServeEngine
+
+    enable_compile_cache()
 
     server, emb, params = build_server(args)
     engine = KGEServeEngine(server, slots=args.slots, max_k=args.topk,

@@ -200,6 +200,8 @@ def main() -> None:
     ap.add_argument("--use-kernel", action="store_true")
     ap.add_argument("--reduced", action="store_true", default=True)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.arch.startswith("rgcn-"):
         train_kge(args)
     else:

@@ -312,11 +312,32 @@ def score_against_candidates(
                                                  candidates, bias)
 
 
+def pairwise_sum(x: jax.Array) -> jax.Array:
+    """Sum over the leading axis in one fixed order: adjacent pairs first,
+    level by level, an odd last element carried up a level.
+
+    ``jnp.sum`` leaves the order to the compiler, which on TPU picks it
+    per program: the same values summed inside an 8-trainer and a
+    4-trainer vmapped step can round differently.  Elementwise adds in a
+    fixed tree leave it no choice.  A contiguous, aligned block of a
+    power-of-two number of elements is a subtree of this order, so summing
+    such blocks first and then their sums gives the same bits as summing
+    everything at once — the property the spmd and simulated train steps
+    share (``repro.training.distributed``)."""
+    while x.shape[0] > 1:
+        n = x.shape[0] - x.shape[0] % 2
+        pairs = x[0:n:2] + x[1:n:2]
+        x = pairs if n == x.shape[0] else jnp.concatenate([pairs, x[n:]])
+    return x[0]
+
+
 def bce_loss(scores: jax.Array, labels: jax.Array,
              mask: jax.Array) -> jax.Array:
     """Paper Eq. 3: mean binary cross-entropy over positives+negatives,
-    numerically stable logits form, padding masked out."""
+    numerically stable logits form, padding masked out, summed in
+    ``pairwise_sum``'s order so the value does not depend on the program
+    it is compiled into."""
     per = jnp.maximum(scores, 0) - scores * labels + \
         jnp.log1p(jnp.exp(-jnp.abs(scores)))
     denom = jnp.maximum(mask.sum(), 1.0)
-    return jnp.sum(per * mask) / denom
+    return pairwise_sum(per * mask) / denom

@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # shards them P(data, model) in its in_specs to match the
 # BatchShardings.plan transfer placement (per-device plan blocks arrive
 # pre-sliced — no resharding on dispatch)
+from repro.models.decoders import pairwise_sum
 from repro.sharding.embedding import PLAN_BATCH_KEYS
 from repro.training.optimizer import Optimizer, apply_updates
 
@@ -63,12 +64,16 @@ def make_simulated_train_step(
     def step(params, opt_state, batch, keys):
         loss, aux, grads = jax.vmap(
             grad_one, in_axes=(None, 0, 0))(params, batch, keys)
-        grads = jax.tree_util.tree_map(
-            lambda g: jnp.mean(g, axis=0), grads)      # AllReduce-average
+        num = loss.shape[0]
+
+        def mean(x):                                   # AllReduce-average
+            return pairwise_sum(x) / num
+
+        grads = jax.tree_util.tree_map(mean, grads)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = apply_updates(params, updates)
-        metrics = {"loss": jnp.mean(loss),
-                   **{k: jnp.mean(v) for k, v in aux.items()}}
+        metrics = {"loss": mean(loss),
+                   **{k: mean(v) for k, v in aux.items()}}
         return params, opt_state, metrics
 
     return step
@@ -164,14 +169,20 @@ def make_spmd_train_step(
 
         loss, aux, grads = jax.vmap(one, in_axes=(None, 0, 0))(
             params, batch, keys)
-        grads = jax.tree_util.tree_map(lambda g: jnp.mean(g, 0), grads)
-        loss = jnp.mean(loss)
+        num = loss.shape[0] * jax.lax.psum(1, data_axes)
+
         # AllReduce over the trainer axes (and leave other axes alone —
         # model-parallel replicas hold identical grads by construction).
-        grads = jax.lax.pmean(grads, axis_name=data_axes)
-        loss = jax.lax.pmean(loss, axis_name=data_axes)
-        aux = jax.tree_util.tree_map(
-            lambda v: jax.lax.pmean(jnp.mean(v), axis_name=data_axes), aux)
+        # The local trainers are summed in pairwise_sum's order, so with up
+        # to two data-axis devices (one rounding for the cross-device add,
+        # whichever device makes it) the result is bitwise the simulated
+        # step's; with more, the all-reduce's own order decides.
+        def mean(x):
+            return jax.lax.psum(pairwise_sum(x), data_axes) / num
+
+        grads = jax.tree_util.tree_map(mean, grads)
+        loss = mean(loss)
+        aux = jax.tree_util.tree_map(mean, aux)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = apply_updates(params, updates)
         return params, opt_state, {"loss": loss, **aux}

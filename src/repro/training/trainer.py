@@ -191,12 +191,12 @@ class KGETrainer:
             else self._minibatch_loss
         if self._spmd:
             from repro.training.distributed import make_spmd_train_step
-            self._step = make_spmd_train_step(
+            self.step = make_spmd_train_step(
                 loss, optimizer, self.mesh,
                 param_specs=self._param_specs,
                 model_axis="model", donate_batch=donate)
         else:
-            self._step = make_simulated_train_step(
+            self.step = make_simulated_train_step(
                 loss, optimizer, donate_batch=donate)
         if self._fullgraph:
             self.pipeline: InputPipeline = FullGraphPipeline(
@@ -314,13 +314,11 @@ class KGETrainer:
                               model_axis=self._model_axis)
 
     # ------------------------------------------------------------------ #
-    def lower_step(self, batch=None):
-        """``jax.stages.Lowered`` of the trainer's jitted train step for
-        one real pipeline batch — the entry point the SPMD contract
-        auditor (``repro.analysis.programs``) lowers each production
-        configuration through.  ``batch`` defaults to the pipeline's
-        first batch of the next epoch; compile the result and read
-        ``.as_text()`` for the post-optimization per-device module."""
+    def next_step_args(self, batch=None) -> tuple:
+        """``(params, opt_state, batch, keys)`` of the next epoch's first
+        step, exactly as ``train_epoch`` passes them to ``step``.
+        ``batch`` defaults to the pipeline's first batch of that epoch.
+        A reference run replays these arguments on another device."""
         if batch is None:
             it = self.pipeline.device_batches(self._epoch + 1)
             batch = next(iter(it))
@@ -331,7 +329,16 @@ class KGETrainer:
                                   self._epoch + 1)
         if not self._fullgraph:
             keys = jax.vmap(jax.random.fold_in, (0, None))(keys, 0)
-        return self._step.lower(self.params, self.opt_state, batch, keys)
+        return self.params, self.opt_state, batch, keys
+
+    def lower_step(self, batch=None):
+        """``jax.stages.Lowered`` of the trainer's jitted train step for
+        one real pipeline batch (:meth:`next_step_args`) — the entry point
+        the SPMD contract auditor (``repro.analysis.programs``) lowers
+        each production configuration through; compile the result and
+        read ``.as_text()`` for the post-optimization per-device
+        module."""
+        return self.step.lower(*self.next_step_args(batch))
 
     # ------------------------------------------------------------------ #
     def train_epoch(self) -> Dict[str, float]:
@@ -349,7 +356,7 @@ class KGETrainer:
                 skeys = jax.vmap(jax.random.fold_in, (0, None))(
                     keys, nbatches)
             t0 = time.perf_counter()
-            self.params, self.opt_state, m = self._step(
+            self.params, self.opt_state, m = self.step(
                 self.params, self.opt_state, batch, skeys)
             jax.block_until_ready(m["loss"])
             t_device += time.perf_counter() - t0

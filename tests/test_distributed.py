@@ -224,6 +224,28 @@ def test_trainer_forced_spmd_matches_simulated_one_device():
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("n,block", [(8, 4), (8, 2), (16, 4), (4, 2)])
+def test_pairwise_sum_block_is_a_subtree(n, block):
+    """Summing each aligned block of trainers first, then the block sums,
+    is bitwise the one-device sum: the order the spmd step (one block
+    per data-axis device) shares with the simulated step."""
+    from repro.models.decoders import pairwise_sum
+    rng = np.random.default_rng(n * block)
+    x = jnp.asarray(rng.normal(size=(n, 33))
+                    * 10.0 ** rng.integers(-6, 6, size=(n, 1)), jnp.float32)
+    blocks = jnp.stack([pairwise_sum(x[i:i + block])
+                        for i in range(0, n, block)])
+    assert (np.asarray(pairwise_sum(blocks))
+            == np.asarray(pairwise_sum(x))).all()
+
+
+def test_pairwise_sum_odd_count_carries_the_last_element():
+    from repro.models.decoders import pairwise_sum
+    x = jnp.asarray([1e8, 1.0, -1e8, 3.0, 0.5], jnp.float32)
+    want = ((x[0] + x[1]) + (x[2] + x[3])) + x[4]
+    assert np.asarray(pairwise_sum(x)) == np.asarray(want)
+
+
 # The tentpole gate: on a FORCED 2-device mesh the spmd trainer (auto-on)
 # must be float-identical in per-epoch losses and bitwise in final params
 # to the simulated trainer, for the mini-batch AND full-graph paths with a

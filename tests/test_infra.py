@@ -384,3 +384,37 @@ class TestServingMoreArchs:
         done = eng.run([Request(0, np.array([1, 2], np.int32),
                                 max_new_tokens=3)])
         assert done[0].done and len(done[0].output) == 3
+
+
+class TestLaunchHelpers:
+    def test_chip_peaks_only_for_known_kinds(self):
+        from repro.launch.mesh import chip_peaks
+        assert chip_peaks("TPU v5 lite")["hbm_bw"] == 819e9
+        for kind in ("TPU v4", "TPU v6 lite", "cpu"):
+            with pytest.raises(ValueError, match="no peak table"):
+                chip_peaks(kind)
+
+    def test_compile_cache_honours_the_environment(self, monkeypatch,
+                                                   tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_compile_cache_defaults_to_one_checkout_dir(self, monkeypatch):
+        from repro.launch.compile_cache import (
+            CHECKOUT_CACHE_DIR, enable_compile_cache,
+        )
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        try:
+            got = enable_compile_cache()
+            assert got == os.path.join(root, ".jax_cache")
+            assert got == str(CHECKOUT_CACHE_DIR) == enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()

@@ -17,6 +17,7 @@ from repro.eval.ranking import (
 )
 from repro.kernels.ops import merge_topk, topk_padded
 from repro.kernels.ref import topk_ref
+from repro.kernels.topk import topk_scores
 from repro.models.decoders import (
     init_decoder_params, registered_decoders, score_against_candidates,
 )
@@ -90,6 +91,25 @@ class TestTopkKernel:
         lv, li = jax.lax.top_k(s, 4)
         assert (np.asarray(ki) == np.asarray(li)).all()
         assert np.isneginf(np.asarray(kv)).all()
+
+    @pytest.mark.parametrize("k", [1, 5, 130])
+    def test_candidate_tiles_merge_exactly(self, k):
+        """A score block cut into 128-column candidate tiles (C = 300:
+        three tiles, the last one partly padding) gives the values AND
+        indices of one ``lax.top_k`` — with ties and ``-inf`` runs that
+        cross tile edges, and with k wider than a tile."""
+        rng = np.random.default_rng(5)
+        scores = rng.normal(size=(128, 384)).astype(np.float32)
+        scores[:, 130] = scores[:, 7]      # ties across tile edges
+        scores[:, 299] = scores[:, 7]
+        scores[3] = -np.inf                # drains in index order
+        scores[4, ::2] = 2.0               # half a row tied at the top
+        scores[:, 300:] = 9.0              # padding must never win
+        kv, ki = topk_scores(jnp.asarray(scores), k, num_cols=300,
+                             c_block=128, interpret=True)
+        lv, li = jax.lax.top_k(jnp.asarray(scores[:, :300]), k)
+        assert (np.asarray(kv) == np.asarray(lv)).all()
+        assert (np.asarray(ki) == np.asarray(li)).all()
 
     def test_k_out_of_range_raises(self):
         s = jnp.zeros((2, 6), jnp.float32)

@@ -215,7 +215,7 @@ class TestExchangeLayouts:
             out = jax.vmap(lambda t: sharded_gather(
                 t[None], li, ow, axis_name="model", exchange=exchange),
                 axis_name="model")(stack)
-            # vmap inlines the exchange's custom VJP (jax 0.4 batching), so
+            # vmap inlines the exchange's custom VJP when it batches it, so
             # this path exercises the COLLECTIVE-TRANSPOSE backward: the
             # loss must consume the replicated output exactly once (shard
             # 0's copy) for the broadcast cotangent to match dense.  The
