@@ -9,6 +9,14 @@ Two execution shapes:
   paper's FB15k-237 setting) with device-side constraint-based negatives.
 
 Both are jit/shard_map friendly (fixed shapes, no host callbacks).
+
+The layers carry ``jax.named_scope`` names, which reach the compiled
+program's HLO ``op_name`` metadata (forward, ``transpose(jvp(...))``
+backward and rematerialized copies alike) and change no value:
+``kge.gather`` (vertex inputs, and under ``jax.grad`` their scatter into
+the table gradient), ``kge.message`` and ``kge.aggregate``
+(``repro.models.rgcn``), ``kge.decoder_loss`` (negatives, scores, loss)
+and ``kge.optimizer`` (``repro.training.distributed``).
 """
 from __future__ import annotations
 
@@ -119,28 +127,30 @@ def minibatch_loss(
     """Loss on one padded EdgeMiniBatch (fields as device arrays; batches
     from a sharded-table pipeline also carry the precomputed gather plan
     under ``shard_local_ids`` / ``shard_owned``)."""
-    x = vertex_input(params, cfg, batch["gather_global"], features,
-                     batch.get("shard_local_ids"),
-                     batch.get("shard_owned"),
-                     batch.get("shard_inverse"), model_axis=model_axis)
-    x = jnp.where(batch["vertex_mask"][:, None], x, 0.0)
+    with jax.named_scope("kge.gather"):
+        x = vertex_input(params, cfg, batch["gather_global"], features,
+                         batch.get("shard_local_ids"),
+                         batch.get("shard_owned"),
+                         batch.get("shard_inverse"), model_axis=model_axis)
+        x = jnp.where(batch["vertex_mask"][:, None], x, 0.0)
     h = rgcn_encode(
         params, cfg.rgcn, x,
         batch["comp_src"], batch["comp_rel"], batch["comp_dst"],
         batch["comp_mask"], dropout_key=dropout_key,
         train=dropout_key is not None)
-    scores = decoders.score_triplets(
-        params["decoder"], cfg.decoder, h, batch["triplets"])
-    mask = batch["triplet_mask"].astype(jnp.float32)
-    loss = decoders.bce_loss(scores, batch["labels"], mask)
-    pos = batch["labels"] > 0.5
-    aux = {
-        "loss": loss,
-        "pos_score_mean": jnp.sum(scores * mask * pos) /
-        jnp.maximum(jnp.sum(mask * pos), 1.0),
-        "neg_score_mean": jnp.sum(scores * mask * (1 - pos)) /
-        jnp.maximum(jnp.sum(mask * (1 - pos)), 1.0),
-    }
+    with jax.named_scope("kge.decoder_loss"):
+        scores = decoders.score_triplets(
+            params["decoder"], cfg.decoder, h, batch["triplets"])
+        mask = batch["triplet_mask"].astype(jnp.float32)
+        loss = decoders.bce_loss(scores, batch["labels"], mask)
+        pos = batch["labels"] > 0.5
+        aux = {
+            "loss": loss,
+            "pos_score_mean": jnp.sum(scores * mask * pos) /
+            jnp.maximum(jnp.sum(mask * pos), 1.0),
+            "neg_score_mean": jnp.sum(scores * mask * (1 - pos)) /
+            jnp.maximum(jnp.sum(mask * (1 - pos)), 1.0),
+        }
     return loss, aux
 
 
@@ -161,34 +171,37 @@ def fullgraph_loss(
     partition's core vertices — legal because the full partition graph is the
     computational graph, so every core vertex already has an embedding."""
     k_neg, k_drop = jax.random.split(rng)
-    x = vertex_input(params, cfg, part["local_to_global"], features,
-                     part.get("shard_local_ids"),
-                     part.get("shard_owned"),
-                     part.get("shard_inverse"), model_axis=model_axis)
-    x = jnp.where(part["vertex_mask"][:, None], x, 0.0)
+    with jax.named_scope("kge.gather"):
+        x = vertex_input(params, cfg, part["local_to_global"], features,
+                         part.get("shard_local_ids"),
+                         part.get("shard_owned"),
+                         part.get("shard_inverse"), model_axis=model_axis)
+        x = jnp.where(part["vertex_mask"][:, None], x, 0.0)
     h = rgcn_encode(
         params, cfg.rgcn, x,
         part["src"], part["rel"], part["dst"], part["edge_mask"],
         dropout_key=k_drop if train else None, train=train)
 
-    pos = jnp.stack([part["src"], part["rel"], part["dst"]], axis=1)
-    if cfg.negative_sampler == "global":
-        # baseline ablation: corrupt with ANY local vertex (the closest
-        # analogue of the closed-world sampler inside one partition's
-        # address space — a true global draw would need remote fetches)
-        neg, _ = global_closed_world_negatives(
-            k_neg, pos, cfg.num_negatives,
-            int(part["local_to_global"].shape[0]))
-    else:
-        neg, _ = constraint_based_negatives(
-            k_neg, pos, cfg.num_negatives, part["num_core_vertices"])
-    trip, labels = mix_pos_neg(pos, neg)
-    core = part["core_edge_mask"].astype(jnp.float32)
-    mask = jnp.concatenate(
-        [core] + [core] * cfg.num_negatives, axis=0)
+    with jax.named_scope("kge.decoder_loss"):
+        pos = jnp.stack([part["src"], part["rel"], part["dst"]], axis=1)
+        if cfg.negative_sampler == "global":
+            # baseline ablation: corrupt with ANY local vertex (the closest
+            # analogue of the closed-world sampler inside one partition's
+            # address space — a true global draw would need remote fetches)
+            neg, _ = global_closed_world_negatives(
+                k_neg, pos, cfg.num_negatives,
+                int(part["local_to_global"].shape[0]))
+        else:
+            neg, _ = constraint_based_negatives(
+                k_neg, pos, cfg.num_negatives, part["num_core_vertices"])
+        trip, labels = mix_pos_neg(pos, neg)
+        core = part["core_edge_mask"].astype(jnp.float32)
+        mask = jnp.concatenate(
+            [core] + [core] * cfg.num_negatives, axis=0)
 
-    scores = decoders.score_triplets(params["decoder"], cfg.decoder, h, trip)
-    loss = decoders.bce_loss(scores, labels, mask)
+        scores = decoders.score_triplets(params["decoder"], cfg.decoder, h,
+                                         trip)
+        loss = decoders.bce_loss(scores, labels, mask)
     return loss, {"loss": loss}
 
 
@@ -199,10 +212,11 @@ def encode_partition(
     params: Dict[str, Any], cfg: KGEConfig, part: Dict[str, jax.Array],
     features: Optional[jax.Array] = None,
 ) -> jax.Array:
-    x = vertex_input(params, cfg, part["local_to_global"], features,
-                     part.get("shard_local_ids"), part.get("shard_owned"),
-                     part.get("shard_inverse"))
-    x = jnp.where(part["vertex_mask"][:, None], x, 0.0)
+    with jax.named_scope("kge.gather"):
+        x = vertex_input(params, cfg, part["local_to_global"], features,
+                         part.get("shard_local_ids"),
+                         part.get("shard_owned"), part.get("shard_inverse"))
+        x = jnp.where(part["vertex_mask"][:, None], x, 0.0)
     return rgcn_encode(
         params, cfg.rgcn, x,
         part["src"], part["rel"], part["dst"], part["edge_mask"])

@@ -14,6 +14,12 @@ The edge-level compute ``m_e = W_{rel_e} h_{dst_e}`` followed by a segment
 sum into ``src_e`` is the hot spot; ``repro.kernels.rgcn_message`` provides
 the Pallas TPU kernel, and this module's ``message_passing_ref`` is the pure
 jnp implementation used as its oracle and as the CPU path.
+
+Each layer's work carries a ``jax.named_scope`` (HLO ``op_name`` metadata
+only; values are untouched): ``kge.message`` for the per-edge messages (the
+tail gather, basis projection and coefficient mix, or the Pallas kernel
+call whole) and ``kge.aggregate`` for the per-vertex update (the edge mask,
+segment sums, degree normalization, self loop, activation, dropout).
 """
 from __future__ import annotations
 
@@ -147,29 +153,35 @@ def message_passing_ref(
     Returns (V, d_out) aggregated neighbor messages (NOT including self loop
     / activation — the layer wrapper adds those).
     """
-    h_t = h[dst]  # (E, d_in) gather tail features
-    if "bases" in lp:
-        # m_e = sum_b a_[rel_e]b (V_b h_t_e): compute B projections once,
-        # then per-edge coefficient mix — O(B·E·d²) -> O(B·V·d² + B·E·d).
-        proj = jnp.einsum("ed,bdo->ebo", h_t, lp["bases"])   # (E, B, d_out)
-        coef = lp["coeffs"][rel]                              # (E, B)
-        msg = jnp.einsum("ebo,eb->eo", proj, coef)
-    elif "blocks" in lp:
-        r, nb, bi, bo = lp["blocks"].shape
-        e = h_t.shape[0]
-        h_blk = h_t.reshape(e, nb, bi)
-        w_e = lp["blocks"][rel]                               # (E, nb, bi, bo)
-        msg = jnp.einsum("enb,enbo->eno", h_blk, w_e).reshape(e, nb * bo)
-    else:
-        w_e = lp["rel_weight"][rel]                           # (E, d_in, d_out)
-        msg = jnp.einsum("ed,edo->eo", h_t, w_e)
+    with jax.named_scope("kge.message"):
+        h_t = h[dst]  # (E, d_in) gather tail features
+        if "bases" in lp:
+            # m_e = sum_b a_[rel_e]b (V_b h_t_e): compute B projections
+            # once, then per-edge coefficient mix — O(B·E·d²) ->
+            # O(B·V·d² + B·E·d).
+            proj = jnp.einsum("ed,bdo->ebo", h_t,
+                              lp["bases"])                    # (E, B, d_out)
+            coef = lp["coeffs"][rel]                          # (E, B)
+            msg = jnp.einsum("ebo,eb->eo", proj, coef)
+        elif "blocks" in lp:
+            r, nb, bi, bo = lp["blocks"].shape
+            e = h_t.shape[0]
+            h_blk = h_t.reshape(e, nb, bi)
+            w_e = lp["blocks"][rel]                           # (E, nb, bi, bo)
+            msg = jnp.einsum("enb,enbo->eno", h_blk, w_e).reshape(e, nb * bo)
+        else:
+            w_e = lp["rel_weight"][rel]                       # (E, d_in, d_out)
+            msg = jnp.einsum("ed,edo->eo", h_t, w_e)
 
-    msg = jnp.where(edge_mask[:, None], msg, 0.0)
-    num_v = h.shape[0]
-    agg = jax.ops.segment_sum(msg, src, num_segments=num_v)
-    deg = jax.ops.segment_sum(edge_mask.astype(h.dtype), src,
-                              num_segments=num_v)
-    return agg / jnp.maximum(deg, 1.0)[:, None]
+    # the edge mask opens the aggregation: the TPU's scatter fusions keep
+    # no metadata of their own and are known by their updates' producer
+    with jax.named_scope("kge.aggregate"):
+        msg = jnp.where(edge_mask[:, None], msg, 0.0)
+        num_v = h.shape[0]
+        agg = jax.ops.segment_sum(msg, src, num_segments=num_v)
+        deg = jax.ops.segment_sum(edge_mask.astype(h.dtype), src,
+                                  num_segments=num_v)
+        return agg / jnp.maximum(deg, 1.0)[:, None]
 
 
 def rgcn_layer(
@@ -179,17 +191,20 @@ def rgcn_layer(
 ) -> jax.Array:
     if cfg.use_kernel and "bases" in lp:
         from repro.kernels.ops import rgcn_message_basis
-        agg = rgcn_message_basis(
-            h, src, rel, dst, edge_mask, lp["bases"], lp["coeffs"])
+        with jax.named_scope("kge.message"):
+            agg = rgcn_message_basis(
+                h, src, rel, dst, edge_mask, lp["bases"], lp["coeffs"])
     else:
         agg = message_passing_ref(h, src, rel, dst, edge_mask, lp, cfg)
-    if cfg.self_loop:
-        agg = agg + h @ lp["self_weight"]
-    out = activation(agg)
-    if dropout_key is not None and cfg.dropout > 0:
-        keep = jax.random.bernoulli(dropout_key, 1 - cfg.dropout, out.shape)
-        out = jnp.where(keep, out / (1 - cfg.dropout), 0.0)
-    return out
+    with jax.named_scope("kge.aggregate"):
+        if cfg.self_loop:
+            agg = agg + h @ lp["self_weight"]
+        out = activation(agg)
+        if dropout_key is not None and cfg.dropout > 0:
+            keep = jax.random.bernoulli(dropout_key, 1 - cfg.dropout,
+                                        out.shape)
+            out = jnp.where(keep, out / (1 - cfg.dropout), 0.0)
+        return out
 
 
 def rgcn_encode(

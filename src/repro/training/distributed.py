@@ -16,6 +16,9 @@ Two step builders with identical math:
 * ``make_simulated_train_step`` — vmap over the trainer axis + mean; runs on
   a single device and is bit-wise the same averaging, used by CPU tests to
   prove distributed == simulated == (for 1 trainer) non-distributed.
+
+In both, the gradient average and the optimizer update run under the named
+scope ``kge.optimizer`` (HLO metadata only).
 """
 from __future__ import annotations
 
@@ -69,9 +72,10 @@ def make_simulated_train_step(
         def mean(x):                                   # AllReduce-average
             return pairwise_sum(x) / num
 
-        grads = jax.tree_util.tree_map(mean, grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope("kge.optimizer"):
+            grads = jax.tree_util.tree_map(mean, grads)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
         metrics = {"loss": mean(loss),
                    **{k: mean(v) for k, v in aux.items()}}
         return params, opt_state, metrics
@@ -180,11 +184,13 @@ def make_spmd_train_step(
         def mean(x):
             return jax.lax.psum(pairwise_sum(x), data_axes) / num
 
-        grads = jax.tree_util.tree_map(mean, grads)
+        with jax.named_scope("kge.optimizer"):
+            grads = jax.tree_util.tree_map(mean, grads)
         loss = mean(loss)
         aux = jax.tree_util.tree_map(mean, aux)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope("kge.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = apply_updates(params, updates)
         return params, opt_state, {"loss": loss, **aux}
 
     from jax.experimental.shard_map import shard_map

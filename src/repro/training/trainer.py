@@ -15,6 +15,9 @@ Timing instrumentation mirrors the paper's Fig. 6 component breakdown:
 critical path (== all of it for the serial pipeline; the exposed remainder
 for the async pipeline), ``t_host_build`` the total host construction time,
 ``overlap_fraction`` how much of it the pipeline hid behind the device step.
+``train_epoch`` also names its host work on the profiler's clock (see its
+docstring), so a recorded trace says what the host did while the device
+idled.
 """
 from __future__ import annotations
 
@@ -25,6 +28,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import KnowledgeGraph
 from repro.data.pipeline import (
@@ -38,7 +42,9 @@ from repro.training.distributed import (
     make_simulated_train_step, split_trainer_keys,
 )
 from repro.training.evaluation import encode_all_entities, evaluate_split
-from repro.training.preprocessing import PreprocessedGraph, preprocess_graph
+from repro.training.preprocessing import (
+    SETUP_EVENT, PreprocessedGraph, preprocess_graph,
+)
 
 
 @dataclasses.dataclass
@@ -147,6 +153,7 @@ class KGETrainer:
             num_negatives=cfg.num_negatives,
             negative_sampler=cfg.negative_sampler,
         )
+        t0 = time.perf_counter()
         key = jax.random.PRNGKey(cfg.seed)
         self.params = init_kge_params(key, self.kge_cfg)
         self.features = None if feat is None else jnp.asarray(feat)
@@ -154,9 +161,11 @@ class KGETrainer:
         optimizer = opt_lib.adam(cfg.learning_rate)
         self.optimizer = optimizer
         self.opt_state = optimizer.init(self.params)
+        jax.monitoring.record_event_duration_secs(
+            f"{SETUP_EVENT}init", time.perf_counter() - t0)
         self._key = jax.random.PRNGKey(cfg.seed + 1)
         self._epoch = 0
-        self.timings: List[Dict[str, float]] = []
+        self._steps = 0     # global step count: the trace's step numbers
 
         # ---- mesh + step selection (simulated vmap vs real shard_map) ----
         self._fullgraph = cfg.batch_size is None
@@ -342,41 +351,61 @@ class KGETrainer:
 
     # ------------------------------------------------------------------ #
     def train_epoch(self) -> Dict[str, float]:
+        """One epoch; its host work opens ``jax.profiler`` spans (about a
+        microsecond each when no trace is recording): ``train.epoch``
+        around it, ``train.keys`` around the key split, a
+        ``pipeline.next_batch`` before each step (the last finds the epoch
+        ended and opens no step), and per step ``train.step`` (a
+        ``StepTraceAnnotation`` numbered by the global step) holding
+        ``train.keys`` (the mini-batch fold-in), ``train.dispatch`` (the
+        jitted step's call) and ``train.wait`` (until its loss is on the
+        host)."""
         cfg = self.cfg
         self._epoch += 1
         t_device = 0.0
         losses = []
-        keys = split_trainer_keys(self._key, cfg.num_trainers, self._epoch)
+        with TraceAnnotation("train.epoch"):
+            with TraceAnnotation("train.keys"):
+                keys = split_trainer_keys(self._key, cfg.num_trainers,
+                                          self._epoch)
+            nbatches = 0
+            batches = iter(self.pipeline.device_batches(self._epoch))
+            while True:
+                with TraceAnnotation("pipeline.next_batch"):
+                    batch = next(batches, None)
+                if batch is None:
+                    break
+                with StepTraceAnnotation("train.step",
+                                         step_num=self._steps):
+                    if self._fullgraph:
+                        skeys = keys  # one update per epoch; keys fresh
+                    else:
+                        with TraceAnnotation("train.keys"):
+                            skeys = jax.vmap(jax.random.fold_in,
+                                             (0, None))(keys, nbatches)
+                    t0 = time.perf_counter()
+                    with TraceAnnotation("train.dispatch"):
+                        self.params, self.opt_state, m = self.step(
+                            self.params, self.opt_state, batch, skeys)
+                    with TraceAnnotation("train.wait"):
+                        jax.block_until_ready(m["loss"])
+                        t_device += time.perf_counter() - t0
+                        losses.append(float(m["loss"]))
+                nbatches += 1
+                self._steps += 1
 
-        nbatches = 0
-        for batch in self.pipeline.device_batches(self._epoch):
-            if self._fullgraph:
-                skeys = keys     # one update per epoch; keys already fresh
-            else:
-                skeys = jax.vmap(jax.random.fold_in, (0, None))(
-                    keys, nbatches)
-            t0 = time.perf_counter()
-            self.params, self.opt_state, m = self.step(
-                self.params, self.opt_state, batch, skeys)
-            jax.block_until_ready(m["loss"])
-            t_device += time.perf_counter() - t0
-            losses.append(float(m["loss"]))
-            nbatches += 1
-
-        stats = self.pipeline.last_stats
-        rec = {
-            "epoch": self._epoch,
-            "loss": float(np.mean(losses)) if losses else float("nan"),
-            "t_get_compute_graph": stats.exposed_wait_s,
-            "t_host_build": stats.host_build_s,
-            "t_warmup": stats.warmup_s,
-            "overlap_fraction": stats.overlap_fraction(),
-            "t_device_step": t_device,
-            "t_epoch": stats.warmup_s + stats.exposed_wait_s + t_device,
-            "num_batches": nbatches,
-        }
-        self.timings.append(rec)
-        return rec
+            stats = self.pipeline.last_stats
+            return {
+                "epoch": self._epoch,
+                "loss": float(np.mean(losses)) if losses else float("nan"),
+                "t_get_compute_graph": stats.exposed_wait_s,
+                "t_host_build": stats.host_build_s,
+                "t_warmup": stats.warmup_s,
+                "overlap_fraction": stats.overlap_fraction(),
+                "t_device_step": t_device,
+                "t_epoch": stats.warmup_s + stats.exposed_wait_s + t_device,
+                "num_batches": nbatches,
+            }
 
     def fit(self, epochs: Optional[int] = None,
             log_fn=None) -> List[Dict[str, float]]:
