@@ -145,6 +145,10 @@ class PaddedPartitionBatch:
     dst: np.ndarray              # (P, E_max) int32
     edge_mask: np.ndarray        # (P, E_max) bool   — real message edges
     core_edge_mask: np.ndarray   # (P, E_max) bool   — real AND core
+    # (P, C_max) int32 — ascending positions of each row's core edges in its
+    # padded edge list, padded with 0 (slots >= num_core_edges); the
+    # full-graph decoder scores only these rows
+    core_edge_index: np.ndarray
     local_to_global: np.ndarray  # (P, V_max) int64  — padded with 0
     vertex_mask: np.ndarray      # (P, V_max) bool
     num_core_vertices: np.ndarray  # (P,) int32
@@ -167,6 +171,12 @@ class PaddedPartitionBatch:
         of GPU straggler time (see DESIGN.md §2)."""
         return 1.0 - float(self.edge_mask.mean())
 
+    def core_slot_fill(self) -> float:
+        """Fraction of ``core_edge_index`` slots that name a real core
+        edge: the share of the full-graph decoder's rows that count."""
+        return float(self.num_core_edges.sum()) / max(
+            self.core_edge_index.size, 1)
+
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
@@ -185,6 +195,7 @@ def pad_partitions(
     e_max = max(p.num_local_edges for p in parts)
     v_max = _round_up(max(v_max, max_vertices or 0), vertex_align)
     e_max = _round_up(max(e_max, max_edges or 0), edge_align)
+    c_max = _round_up(max(p.num_core_edges for p in parts), edge_align)
     n = len(parts)
 
     out = PaddedPartitionBatch(
@@ -193,6 +204,7 @@ def pad_partitions(
         dst=np.zeros((n, e_max), np.int32),
         edge_mask=np.zeros((n, e_max), bool),
         core_edge_mask=np.zeros((n, e_max), bool),
+        core_edge_index=np.zeros((n, c_max), np.int32),
         local_to_global=np.zeros((n, v_max), np.int64),
         vertex_mask=np.zeros((n, v_max), bool),
         num_core_vertices=np.zeros(n, np.int32),
@@ -205,6 +217,8 @@ def pad_partitions(
         out.dst[i, :e] = p.dst
         out.edge_mask[i, :e] = True
         out.core_edge_mask[i, :e] = p.core_edge_mask
+        core = np.flatnonzero(p.core_edge_mask)
+        out.core_edge_index[i, :core.size] = core
         out.local_to_global[i, :v] = p.local_to_global
         out.vertex_mask[i, :v] = True
         out.num_core_vertices[i] = p.num_core_vertices

@@ -169,7 +169,9 @@ def fullgraph_loss(
     """Full-edge-batch training step on one padded partition (paper's
     FB15k-237 configuration).  Negatives are sampled ON DEVICE from the
     partition's core vertices — legal because the full partition graph is the
-    computational graph, so every core vertex already has an embedding."""
+    computational graph, so every core vertex already has an embedding.
+    Every local edge carries messages; only the core edges, listed by
+    ``core_edge_index``, are scored."""
     k_neg, k_drop = jax.random.split(rng)
     with jax.named_scope("kge.gather"):
         x = vertex_input(params, cfg, part["local_to_global"], features,
@@ -184,6 +186,8 @@ def fullgraph_loss(
 
     with jax.named_scope("kge.decoder_loss"):
         pos = jnp.stack([part["src"], part["rel"], part["dst"]], axis=1)
+        # negatives are drawn for every local edge, so each edge keeps the
+        # corruptions its key gives it whatever rows are scored below
         if cfg.negative_sampler == "global":
             # baseline ablation: corrupt with ANY local vertex (the closest
             # analogue of the closed-world sampler inside one partition's
@@ -194,10 +198,16 @@ def fullgraph_loss(
         else:
             neg, _ = constraint_based_negatives(
                 k_neg, pos, cfg.num_negatives, part["num_core_vertices"])
-        trip, labels = mix_pos_neg(pos, neg)
-        core = part["core_edge_mask"].astype(jnp.float32)
-        mask = jnp.concatenate(
-            [core] + [core] * cfg.num_negatives, axis=0)
+        # score only the core edges (support edges carry messages, not
+        # loss): their positives and their own s negatives, edge-major
+        idx = part["core_edge_index"]
+        c, s = idx.shape[0], cfg.num_negatives
+        neg = neg.reshape(pos.shape[0], s, 3)[idx].reshape(c * s, 3)
+        trip, labels = mix_pos_neg(pos[idx], neg)
+        core = (part["core_edge_mask"][idx]
+                & (jnp.arange(c) < part["num_core_edges"]))
+        core = core.astype(jnp.float32)
+        mask = jnp.concatenate([core, jnp.repeat(core, s)], axis=0)
 
         scores = decoders.score_triplets(params["decoder"], cfg.decoder, h,
                                          trip)

@@ -46,6 +46,11 @@ from repro.training.preprocessing import (
     SETUP_EVENT, PreprocessedGraph, preprocess_graph,
 )
 
+# JAX monitoring scalar: the share of the full-graph decoder's rows that
+# are real core edges (``PaddedPartitionBatch.core_slot_fill``), recorded
+# once at set-up
+CORE_SLOT_FILL_EVENT = "/repro/decoder/core_slot_fill"
+
 
 @dataclasses.dataclass
 class TrainConfig:
@@ -208,6 +213,8 @@ class KGETrainer:
             self.step = make_simulated_train_step(
                 loss, optimizer, donate_batch=donate)
         if self._fullgraph:
+            jax.monitoring.record_scalar(
+                CORE_SLOT_FILL_EVENT, self.pre.padded.core_slot_fill())
             self.pipeline: InputPipeline = FullGraphPipeline(
                 self.pre.padded, table_layout=self.pre.table_layout,
                 shardings=shardings)

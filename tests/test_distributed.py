@@ -98,6 +98,171 @@ def test_single_trainer_step_equals_plain_step(small_kg):
                                    rtol=2e-4, atol=1e-6)
 
 
+# ====================================================================== #
+# The full-graph decoder scores only the core edges (core_edge_index)
+# ====================================================================== #
+def _masked_fullgraph_loss(params, cfg, part, rng, train):
+    """The all-edge formulation: every local edge and its negatives
+    scored, each row weighted by its edge's core mask (s = 1, where the
+    mask tiles over the negatives edge for edge)."""
+    from repro.core.negative import (
+        constraint_based_negatives, global_closed_world_negatives,
+        mix_pos_neg,
+    )
+    from repro.models import decoders
+    from repro.models.kge import vertex_input
+    from repro.models.rgcn import rgcn_encode
+    assert cfg.num_negatives == 1
+    k_neg, k_drop = jax.random.split(rng)
+    x = vertex_input(params, cfg, part["local_to_global"], None)
+    x = jnp.where(part["vertex_mask"][:, None], x, 0.0)
+    h = rgcn_encode(params, cfg.rgcn, x, part["src"], part["rel"],
+                    part["dst"], part["edge_mask"],
+                    dropout_key=k_drop if train else None, train=train)
+    pos = jnp.stack([part["src"], part["rel"], part["dst"]], axis=1)
+    if cfg.negative_sampler == "global":
+        neg, _ = global_closed_world_negatives(
+            k_neg, pos, 1, int(part["local_to_global"].shape[0]))
+    else:
+        neg, _ = constraint_based_negatives(
+            k_neg, pos, 1, part["num_core_vertices"])
+    trip, labels = mix_pos_neg(pos, neg)
+    core = part["core_edge_mask"].astype(jnp.float32)
+    scores = decoders.score_triplets(params["decoder"], cfg.decoder, h, trip)
+    return decoders.bce_loss(scores, labels, jnp.concatenate([core, core]))
+
+
+@pytest.mark.parametrize("train", [False, True])
+@pytest.mark.parametrize("sampler", ["constraint", "global"])
+@pytest.mark.parametrize("decoder", ["distmult", "transe"])
+def test_fullgraph_loss_core_rows_equal_masked_all_edges(
+        small_kg, decoder, sampler, train):
+    """Scoring the compacted core rows gives the masked all-edge loss and
+    gradient: the support rows it leaves out weigh exactly zero."""
+    cfg, _, batch = _setup(small_kg, 4)
+    cfg = dataclasses.replace(
+        cfg, decoder=decoder, negative_sampler=sampler,
+        rgcn=dataclasses.replace(cfg.rgcn, dropout=0.3))
+    params = init_kge_params(jax.random.PRNGKey(0), cfg)
+    assert batch["core_edge_index"].shape[1] < batch["src"].shape[1]
+    for i in range(2):
+        part = jax.tree_util.tree_map(lambda x: x[i], batch)
+        key = jax.random.PRNGKey(10 + i)
+        l_c, g_c = jax.value_and_grad(lambda p: fullgraph_loss(
+            p, cfg, part, key, train=train)[0])(params)
+        l_m, g_m = jax.value_and_grad(lambda p: _masked_fullgraph_loss(
+            p, cfg, part, key, train))(params)
+        # the loss sums its rows in a tree whose shape follows the row
+        # count, so it may round differently; the gradient may not
+        np.testing.assert_allclose(float(l_c), float(l_m), rtol=1e-6)
+        for a, b in zip(jax.tree_util.tree_leaves(g_c),
+                        jax.tree_util.tree_leaves(g_m)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_fullgraph_loss_weights_each_negative_by_its_own_edge(small_kg):
+    """With s = 2 negatives per edge, every negative is weighted by the
+    core mask of the edge it corrupts: the loss equals a per-triplet loop
+    over the local edges."""
+    from repro.core.negative import corrupt_triplets
+    from repro.models.kge import encode_partition
+    cfg, params, batch = _setup(small_kg, 4)
+    s = 2
+    cfg = dataclasses.replace(cfg, num_negatives=s)
+    part = jax.tree_util.tree_map(lambda x: x[1], batch)
+    key = jax.random.PRNGKey(7)
+    loss = float(fullgraph_loss(params, cfg, part, key, train=False)[0])
+
+    k_neg, _ = jax.random.split(key)
+    h = np.asarray(encode_partition(params, cfg, part), np.float64)
+    rel_diag = np.asarray(params["decoder"]["rel_diag"], np.float64)
+    pos = np.stack([np.asarray(part[k]) for k in ("src", "rel", "dst")], 1)
+    neg = np.asarray(corrupt_triplets(k_neg, jnp.asarray(pos), s,
+                                      part["num_core_vertices"])[0])
+    core = np.asarray(part["core_edge_mask"])
+
+    def bce(t, label):
+        x = float(np.sum(h[t[0]] * rel_diag[t[1]] * h[t[2]]))
+        return max(x, 0.0) - x * label + np.log1p(np.exp(-abs(x)))
+
+    total = count = 0.0
+    for e in range(pos.shape[0]):
+        if not core[e]:
+            continue
+        total += bce(pos[e], 1.0)
+        for j in range(s):
+            total += bce(neg[e * s + j], 0.0)
+        count += 1 + s
+    assert count == (1 + s) * int(part["num_core_edges"])
+    np.testing.assert_allclose(loss, total / count, rtol=1e-5)
+
+
+_DATA_MESH_SCRIPT = """
+import dataclasses
+import jax, jax.numpy as jnp, numpy as np
+assert jax.device_count() == 2, jax.devices()
+from repro.core import expand_all, make_synthetic_kg, pad_partitions, \\
+    partition_graph
+from repro.launch.mesh import make_host_mesh
+from repro.models import KGEConfig, RGCNConfig, fullgraph_loss, \\
+    init_kge_params
+from repro.training import adam
+from repro.training.distributed import (
+    make_simulated_train_step, make_spmd_train_step,
+)
+
+kg = make_synthetic_kg(200, 6, 1600, seed=1).with_inverse_relations()
+pb = pad_partitions(expand_all(
+    kg, partition_graph(kg, 2, "vertex_cut", seed=0), 2))
+assert pb.core_edge_index.shape[1] < pb.padded_edges
+batch = {f.name: jnp.asarray(getattr(pb, f.name))
+         for f in dataclasses.fields(pb)}
+mesh = make_host_mesh(2, 1)                      # data=2 x model=1
+opt = adam(0.01)
+for s in (1, 2):
+    cfg = KGEConfig(rgcn=RGCNConfig(
+        num_entities=kg.num_entities, num_relations=kg.num_relations,
+        hidden_dim=16, num_layers=2, num_bases=2, dropout=0.2),
+        num_negatives=s)
+    params = init_kge_params(jax.random.PRNGKey(0), cfg)
+    loss = lambda p, b, k: fullgraph_loss(p, cfg, b, k)
+    step_spmd = make_spmd_train_step(loss, opt, mesh)
+    step_sim = make_simulated_train_step(loss, opt)
+    states = [(params, opt.init(params)), (params, opt.init(params))]
+    for t in range(2):
+        keys = jax.random.split(jax.random.PRNGKey(2 + t), 2)
+        outs = [step(p, o, batch, keys)
+                for step, (p, o) in zip((step_spmd, step_sim), states)]
+        (p1, o1, m1), (p2, o2, m2) = outs
+        assert float(m1["loss"]) == float(m2["loss"]), (s, t)
+        for a, b in zip(jax.tree_util.tree_leaves((p1, o1)),
+                        jax.tree_util.tree_leaves((p2, o2))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        states = [(p1, o1), (p2, o2)]
+print("DATA_MESH_OK")
+"""
+
+
+def test_spmd_fullgraph_core_rows_two_device_data_mesh_matches_simulated():
+    """On a forced 2-device data mesh (one trainer per device) the spmd
+    full-graph step, scoring each trainer's core rows, is bitwise the
+    simulated step: losses, parameters and optimizer state, two steps
+    deep, with one and with two negatives per edge."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(repo, "src")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        " --xla_force_host_platform_device_count=2").strip()
+    proc = subprocess.run(
+        [sys.executable, "-c", _DATA_MESH_SCRIPT], cwd=repo, env=env,
+        capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "DATA_MESH_OK" in proc.stdout
+
+
 @pytest.mark.slow
 def test_end_to_end_accuracy_parity():
     """Table 3 structure at toy scale: 4-trainer distributed training

@@ -97,6 +97,30 @@ class TestPadding:
             assert (pb.src[i, :e] == sp.src).all()
             assert (pb.edge_mask[i, e:] == False).all()  # noqa: E712
 
+    @pytest.mark.parametrize("p", [1, 4])
+    def test_core_edge_index(self, small_kg, p):
+        """Each row lists its partition's core edge positions, ascending;
+        the padded slots lie at or beyond ``num_core_edges``."""
+        exp = expand_all(small_kg, partition_graph(
+            small_kg, p, "vertex_cut", seed=0), num_hops=2)
+        pb = pad_partitions(exp)
+        c_max = pb.core_edge_index.shape[1]
+        assert pb.core_edge_index.shape == (p, c_max)
+        assert pb.core_edge_index.dtype == np.int32
+        assert c_max % 128 == 0
+        assert c_max - 128 < pb.num_core_edges.max() <= c_max <= \
+            pb.padded_edges
+        for i in range(p):
+            n = int(pb.num_core_edges[i])
+            assert n == exp[i].num_core_edges
+            np.testing.assert_array_equal(
+                pb.core_edge_index[i, :n],
+                np.flatnonzero(pb.core_edge_mask[i]))
+            assert (pb.core_edge_index[i, n:] == 0).all()
+        fill = pb.core_slot_fill()
+        assert 0.0 < fill <= 1.0
+        assert fill == pb.num_core_edges.sum() / (p * c_max)
+
 
 @settings(max_examples=15, deadline=None)
 @given(

@@ -438,6 +438,42 @@ class TestModelEquivalence:
             g_s["entity_embedding"], small_kg.num_entities)
         _tree_equal(g_d, g_s)
 
+    @pytest.mark.parametrize("s", SHARD_COUNTS)
+    def test_fullgraph_core_rows_equal_all_rows_sharded(self, small_kg, s):
+        """With a row-sharded table, scoring the core rows equals scoring
+        every local edge under its core mask (the index set to every
+        position), and the padded index slots weigh nothing whatever edge
+        they name."""
+        parts = expand_all(
+            small_kg, partition_graph(small_kg, 4, "vertex_cut", seed=0), 2)
+        pb = pad_partitions(parts)
+        part = {f.name: jnp.asarray(getattr(pb, f.name)[2])
+                for f in dataclasses.fields(pb)}
+        e = pb.padded_edges
+        n = int(part["num_core_edges"])
+        every = {**part, "core_edge_index": jnp.arange(e, dtype=jnp.int32),
+                 "num_core_edges": jnp.int32(e)}
+        slots = part["core_edge_index"].shape[0]
+        shuffled = {**part, "core_edge_index": part["core_edge_index"].at[
+            n:].set(jnp.arange(slots - n, dtype=jnp.int32) % e)}
+        _, cfg_s = _configs(small_kg, s)
+        cfg_s = dataclasses.replace(
+            cfg_s, rgcn=dataclasses.replace(cfg_s.rgcn, dropout=0.2))
+        p_shard = init_kge_params(jax.random.PRNGKey(0), cfg_s)
+        key = jax.random.PRNGKey(4)
+
+        def loss_grad(b):
+            return jax.value_and_grad(lambda p: fullgraph_loss(
+                p, cfg_s, b, key)[0])(p_shard)
+
+        l_c, g_c = loss_grad(part)
+        l_a, g_a = loss_grad(every)
+        np.testing.assert_allclose(float(l_c), float(l_a), rtol=1e-6)
+        _tree_equal(g_c, g_a)
+        l_x, g_x = loss_grad(shuffled)
+        assert float(l_x) == float(l_c)
+        _tree_equal(g_x, g_c)
+
     def test_encode_all_entities_matches(self, small_kg):
         from repro.training.evaluation import encode_all_entities
         cfg_d, cfg_s = _configs(small_kg, 2)

@@ -177,3 +177,26 @@ def test_trainer_reports_its_setup_stages():
         jax.monitoring.unregister_event_duration_listener(listen)
     assert set(heard) == set(tr.pre.seconds) | {"init"}
     assert heard["init"] > 0
+
+
+@pytest.mark.parametrize("batch_size", [None, 256])
+def test_trainer_records_core_slot_fill(batch_size):
+    """A full-graph trainer records, once at set-up, the share of its
+    decoder's rows that are real core edges; a mini-batch one does not."""
+    from repro.training.trainer import CORE_SLOT_FILL_EVENT
+    heard = []
+
+    def listen(event, value, **_):
+        if event == CORE_SLOT_FILL_EVENT:
+            heard.append(value)
+
+    jax.monitoring.register_scalar_listener(listen)
+    try:
+        tr = _trainer(batch_size=batch_size)
+    finally:
+        jax.monitoring.unregister_scalar_listener(listen)
+    if batch_size is None:
+        assert heard == [tr.padded.core_slot_fill()]
+        assert 0.0 < heard[0] <= 1.0
+    else:
+        assert heard == []
