@@ -14,41 +14,65 @@ last one counted whole, time included.
 ``train_edges_per_s`` is the real positive training edges (core edges,
 inverses included; no padding, no negatives) of every epoch of the
 window, over the window.
+
+A traced run also counts each step's real rows (``harness.StepProbe``)
+and, once the window has closed and the peak memory is read, compiles the
+step again for the shapes the window ran, so that the trace's device
+operations are read by the program's named scopes (``bench/scopes.py``).
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import sys
 import time
 
 import numpy as np
 
-from bench import graphs, harness, trace
+from bench import graphs, harness, scopes, trace
+
+
+# keys of a configuration file that the harness reads itself; every other
+# key names a ``TrainConfig`` field and is passed to the trainer as it is
+HARNESS_KEYS = frozenset({
+    "name", "source", "reference", "scale", "dataset", "matmul_precision",
+    "adam", "reduced", "assumed", "rehearse"})
+# ``TrainConfig`` fields the harness sets: the dataset's seed, one epoch
+# per ``train_epoch`` call
+SET_BY_HARNESS = frozenset({"seed", "epochs"})
+
+
+def train_config(cfg: dict):
+    """The ``TrainConfig`` a configuration states: every top-level key that
+    names one of its fields, as it is, with ``seed`` the dataset's and
+    ``epochs`` 1.  A key that is neither a field nor the harness's own is
+    an error, so that a misspelt setting never falls back to a default."""
+    from repro.training import TrainConfig
+
+    fields = {f.name for f in dataclasses.fields(TrainConfig)}
+    unknown = sorted(set(cfg) - HARNESS_KEYS - (fields - SET_BY_HARNESS))
+    if unknown:
+        raise harness.Error(
+            f"configuration {cfg.get('name')!r}: {', '.join(unknown)} "
+            "neither a TrainConfig field the configuration may set nor a "
+            "key of the harness")
+    return TrainConfig(**{k: v for k, v in cfg.items() if k in fields},
+                       seed=int(cfg["dataset"]["seed"]), epochs=1)
 
 
 def build_trainer(ctx):
     """The dataset and the trainer, as the configuration states them."""
     from repro.core import KnowledgeGraph
-    from repro.training import KGETrainer, TrainConfig
+    from repro.training import KGETrainer
 
-    cfg = ctx.cfg
-    data = graphs.make_dataset(cfg)
+    tcfg = train_config(ctx.cfg)
+    data = graphs.make_dataset(ctx.cfg)
     splits = {name: KnowledgeGraph(
         src=t[:, 0], rel=t[:, 1], dst=t[:, 2],
         num_entities=data["num_entities"],
         num_relations=data["num_relations"], features=data["features"])
         for name, t in data["splits"].items()}
-    tcfg = TrainConfig(
-        num_trainers=cfg["num_trainers"], strategy=cfg["strategy"],
-        num_hops=cfg["num_hops"], hidden_dim=cfg["hidden_dim"],
-        num_bases=cfg["num_bases"], num_negatives=cfg["num_negatives"],
-        negative_sampler=cfg["negative_sampler"],
-        batch_size=cfg["batch_size"], learning_rate=cfg["learning_rate"],
-        dropout=cfg["dropout"], decoder=cfg["decoder"],
-        table_dtype=cfg["table_dtype"],
-        num_table_shards=cfg["num_table_shards"],
-        seed=int(cfg["dataset"]["seed"]), epochs=1)
     t0 = time.perf_counter()
     trainer = KGETrainer(splits, tcfg)
     return data, trainer, time.perf_counter() - t0
@@ -147,8 +171,7 @@ def run(ctx) -> dict:
         t0 = time.perf_counter()
         with ctx.span("bench.window"):
             while True:
-                with ctx.span("train.epoch"):
-                    epochs.append(trainer.train_epoch())
+                epochs.append(trainer.train_epoch())
                 if time.perf_counter() - t0 >= ctx.seconds:
                     break
         window_s = time.perf_counter() - t0
@@ -163,14 +186,24 @@ def run(ctx) -> dict:
           f"{gcw.report()}; device memory "
           f"{harness.memory_stats(jax.devices()[0])}", file=sys.stderr)
     counters = {
-        "window_s": window_s, "host_prep_s": host_prep_s,
+        "window_s": window_s, "host_prep_s": host_prep_s, "steps": steps,
         "input_wait_s": sum(e["t_warmup"] + e["t_get_compute_graph"]
                             for e in epochs),
     }
+    reduced = None
     if probe is not None:
         counters["train"] = probe.counts(ctx.cfg)
         probe.detach()
-    reduced = session.reduce() if session is not None else None
+        # the step's instructions by named scope, from the step compiled
+        # for the shapes the window ran (after the window and the memory
+        # read, so that neither moves)
+        t1 = time.perf_counter()
+        module, by_instruction = scopes.instruction_scopes(
+            trainer.lower_step(probe.last_batch).compile().as_text())
+        print(f"train: step module {module}, {len(by_instruction)} "
+              f"instructions, lowered and compiled in "
+              f"{time.perf_counter() - t1:.3f} s", file=sys.stderr)
+        reduced = session.reduce(module, by_instruction)
 
     # free the program's state before the reference runs on the chip
     trainer.close()

@@ -1,17 +1,19 @@
-"""Helpers the drivers share: keys from a seed of any size, the peak device
-memory, and the pass-through probes that a traced run (``--trace 1``)
-installs around the calls into each layer.
+"""Helpers the drivers share: the error that ends a run without a result,
+keys from a seed of any size, the peak device memory, and the
+pass-through probe that a traced run (``--trace 1``) installs around the
+training step.
 
-A probe only opens a host span (``jax.profiler.TraceAnnotation``) around
-a call and counts what the call was given; it changes no argument and no
-result.  Runs with ``--trace 0`` install none, so the timed path is the
-program's own.
+A probe counts what the call was given; it changes no argument and no
+result and opens no span (the program opens its own).  Runs with
+``--trace 0`` install none, so the timed path is the program's own.
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
+
+
+class Error(Exception):
+    """A run that cannot produce a result: exit non-zero, print none."""
 
 
 def seed_keys(seed: int):
@@ -43,22 +45,13 @@ def peak_memory(devices) -> int:
     return max(peaks)
 
 
-def spanned(fn, name: str):
-    import jax
-
-    @functools.wraps(fn)
-    def call(*args, **kwargs):
-        with jax.profiler.TraceAnnotation(name):
-            return fn(*args, **kwargs)
-    return call
-
-
 class StepProbe:
-    """Training: spans around the pipeline's next batch and the step's
-    dispatch, and the real vertex, edge and triplet counts of every step
+    """Training: the real vertex, edge and triplet counts of every step
     (the full-graph batch is resident, so its counts are read once from
     the host copy; a streamed batch's are summed on the device as it is
-    handed to the step)."""
+    handed to the step), and the last batch the step was given (its
+    buffers may have been donated to the step; its shapes, types and
+    placements are what a lowering of the step needs)."""
 
     KEYS = ("vertex_mask", "comp_mask", "triplet_mask")
 
@@ -67,8 +60,8 @@ class StepProbe:
         import jax.numpy as jnp
         self.trainer = trainer
         self.orig_step = trainer.step
-        self.orig_batches = trainer.pipeline.device_batches
         self.sums = []
+        self.last_batch = None
         self.full = trainer._fullgraph
         if not self.full:
             # compiled here, in set-up, so that the window compiles nothing
@@ -76,27 +69,14 @@ class StepProbe:
                                              for k in self.KEYS])
             self._count({k: example_batch[k] for k in self.KEYS})
         trainer.step = self._step
-        trainer.pipeline.device_batches = self._batches
-
-    def _batches(self, epoch):
-        import jax
-        it = iter(self.orig_batches(epoch))
-        while True:
-            with jax.profiler.TraceAnnotation("pipeline.next_batch"):
-                try:
-                    batch = next(it)
-                except StopIteration:
-                    return
-            yield batch
 
     def _step(self, params, opt_state, batch, keys):
-        import jax
         if self.full:
             self.sums.append(None)
         else:
             self.sums.append(self._count({k: batch[k] for k in self.KEYS}))
-        with jax.profiler.TraceAnnotation("step.dispatch"):
-            return self.orig_step(params, opt_state, batch, keys)
+        self.last_batch = batch
+        return self.orig_step(params, opt_state, batch, keys)
 
     def step_counts(self):
         """``(vertices, edges, triplets)`` per trainer, for each step."""
@@ -119,7 +99,6 @@ class StepProbe:
 
     def detach(self):
         self.trainer.step = self.orig_step
-        self.trainer.pipeline.device_batches = self.orig_batches
 
 
 class GcWatch:

@@ -85,17 +85,13 @@ def breakdown(args) -> dict:
         from jax.profiler import ProfileData
         path = glob.glob(os.path.join(session.dir, "**", "*.xplane.pb"),
                          recursive=True)[0]
-        profile = ProfileData.from_file(path)
-        reduced = trace.reduce(profile, chips)
-        lo, hi = next(s[1:] for _, sp in trace.host_spans(profile)
-                      for s in sp if s[0] == trace.WINDOW_SPAN)
-        layers = scopes.attribute(profile, chips, lo, hi, module,
-                                  by_instruction)
+        reduced = trace.reduce(ProfileData.from_file(path), chips,
+                               module=module, scopes=by_instruction)
     finally:
         shutil.rmtree(session.dir, ignore_errors=True)
         trainer.close()
 
-    step_s = layers["step_module_s"]
+    step_s = reduced["step_module_s"]
     out = {"workload": args.workload, "seed": args.seed, "steps": steps,
            "device": {"platform": devices[0].platform,
                       "kind": devices[0].device_kind, "count": chips},
@@ -103,8 +99,8 @@ def breakdown(args) -> dict:
            "step_module_share_of_busy": None}
     if tpu and step_s > 0:
         out["scope_ms_per_step"] = {k: 1000 * v / steps
-                                    for k, v in layers["scope_s"].items()}
-        out["unscoped_share"] = (100 * layers["scope_s"][scopes.UNSCOPED]
+                                    for k, v in reduced["scope_s"].items()}
+        out["unscoped_share"] = (100 * reduced["scope_s"][scopes.UNSCOPED]
                                  / step_s)
         out["step_module_share_of_busy"] = 100 * step_s / reduced["busy_s"]
     if tpu:

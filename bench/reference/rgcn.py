@@ -99,6 +99,12 @@ def _vertex_input(params, features, ids, vmask):
     return jnp.where(vmask[:, None], table[ids], 0.0)
 
 
+def triplet_weights(core, num_negatives):
+    """Each scored row's weight: a positive edge's core mask, then each
+    negative's own edge's (row ``e * s + j`` corrupts edge ``e``)."""
+    return jnp.concatenate([core, jnp.repeat(core, num_negatives)])
+
+
 def fullgraph_loss(params, features, part, key, num_negatives, dropout):
     """One trainer's full-edge-batch loss on its padded partition."""
     k_neg, k_drop = jax.random.split(key)
@@ -121,8 +127,7 @@ def fullgraph_loss(params, features, part, key, num_negatives, dropout):
     trip = jnp.concatenate([pos, neg.reshape(e * s, 3)], axis=0)
     labels = jnp.concatenate([jnp.ones(e), jnp.zeros(e * s)]).astype(
         h.dtype)
-    core = part["core_edge_mask"].astype(h.dtype)
-    mask = jnp.concatenate([core] * (1 + s))
+    mask = triplet_weights(part["core_edge_mask"].astype(h.dtype), s)
     scores = _distmult(h, params["decoder"]["rel_diag"], trip)
     return _bce(scores, labels, mask)
 

@@ -48,9 +48,7 @@ if ROOT not in sys.path:
 # what a later run must find, so it never moves)
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 
-
-class Error(Exception):
-    """A run that cannot produce a result: exit non-zero, print none."""
+from bench.harness import Error  # noqa: E402
 
 
 def load_json(path: str) -> dict:

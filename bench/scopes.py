@@ -1,9 +1,9 @@
 """Device time of a jitted step by the program's named scopes.
 
 The program names each layer of its train step with ``jax.named_scope``
-(``kge.gather``, ``kge.message``, ``kge.aggregate``, ``kge.decoder_loss``,
-``kge.optimizer``), which reaches the compiled HLO as ``op_name``
-metadata.  The TPU trace names each device operation by its HLO
+(the R-GCN step's are ``SCOPES``; a configuration may add its own, and
+every ``kge.<name>`` counts), which reaches the compiled HLO as
+``op_name`` metadata.  The TPU trace names each device operation by its HLO
 instruction text without that metadata, so the scopes are read from the
 step's compiled HLO text (``compiled.as_text()``) and joined to the trace
 by instruction name:
@@ -28,6 +28,7 @@ import bisect
 import re
 from typing import Dict, List, Optional, Tuple
 
+# the R-GCN train step's scopes
 SCOPES = ("kge.gather", "kge.message", "kge.aggregate", "kge.decoder_loss",
           "kge.optimizer")
 UNSCOPED = "unscoped"
@@ -37,12 +38,14 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s")
 _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _CALLS = re.compile(r"\bcalls=%([\w.\-]+)")
-_SCOPE = re.compile(r"kge\.[a-z_]+")
+# a scope is a whole name on the path, bare or wrapped in transformations
+# (``transpose(jvp(kge.message))``)
+_SCOPE = re.compile(r"(?<![\w.])kge\.[a-z0-9_]+(?![\w.])")
 
 
 def innermost(op_name: str) -> str:
     """The innermost ``kge.*`` scope on an ``op_name`` path."""
-    found = [s for s in _SCOPE.findall(op_name) if s in SCOPES]
+    found = _SCOPE.findall(op_name)
     return found[-1] if found else UNSCOPED
 
 
@@ -106,13 +109,16 @@ def attribute(profile, chips: int, lo: int, hi: int, module: str,
               scopes: Dict[str, str]) -> dict:
     """Device seconds of the step module's operations inside ``[lo, hi)``
     (nanoseconds on the profile's clock), by scope, summed over the chips
-    and divided by their number: ``scope_s`` (every scope of ``SCOPES``
-    and ``unscoped``) and ``step_module_s``, their sum."""
+    and divided by their number: ``scope_s`` (every scope that ``scopes``
+    names, and ``unscoped``), ``step_module_s``, their sum, and
+    ``module_runs``, the runs of the module that the device planes'
+    ``XLA Modules`` lines hold inside the window."""
     from bench.trace import clip, device_planes, length
 
     planes = device_planes(profile, chips)
-    ns = {s: 0 for s in SCOPES + (UNSCOPED,)}
+    ns = {s: 0 for s in sorted(set(scopes.values()) | {UNSCOPED})}
     prefix = module + "("
+    module_runs = 0
     for plane in planes:
         runs, ops = [], []
         for line in plane.lines:
@@ -121,6 +127,7 @@ def attribute(profile, chips: int, lo: int, hi: int, module: str,
                               if n == module or n.startswith(prefix))
             elif line.name == "XLA Ops":
                 ops = list(_events(line))
+        module_runs += sum(1 for a, b in runs if a < hi and b > lo)
         starts = [a for a, _ in runs]
         for name, a, b in ops:
             i = bisect.bisect_right(starts, a) - 1
@@ -131,4 +138,24 @@ def attribute(profile, chips: int, lo: int, hi: int, module: str,
                 ns[scopes.get(_instruction(name), UNSCOPED)] += length(iv)
     n = max(len(planes), 1)
     scope_s = {k: v / n / 1e9 for k, v in ns.items()}
-    return {"scope_s": scope_s, "step_module_s": sum(scope_s.values())}
+    return {"scope_s": scope_s, "step_module_s": sum(scope_s.values()),
+            "module_runs": module_runs}
+
+
+def ms_per_step(rec: dict, scope: str) -> Optional[float]:
+    """A traced run's device time in ``scope`` per step of the window, in
+    ms; ``None`` where the trace has no device plane or its step names no
+    such scope."""
+    t = rec["trace"]
+    if not t or not t["device_planes"] or scope not in t.get("scope_s", {}):
+        return None
+    return 1000.0 * t["scope_s"][scope] / rec["counters"]["steps"]
+
+
+def unscoped_share(rec: dict) -> Optional[float]:
+    """The share of the step module's device time under no named scope, in
+    percent; ``None`` where the trace has no device plane or no step."""
+    t = rec["trace"]
+    if not t or not t["device_planes"] or not t.get("step_module_s"):
+        return None
+    return 100.0 * t["scope_s"][UNSCOPED] / t["step_module_s"]

@@ -20,7 +20,10 @@ the cell uses:
 * ``breakdown``: the ten operations that took most device time, and the
   idle time of the window by what the host was doing, named by the
   innermost benchmark span open on the thread that owns the window (gaps
-  with no span open are named ``host``).
+  with no span open are named ``host``);
+* given the step's module name and its instructions' scopes
+  (``bench/scopes.py``): ``scope_s``, the step module's device time by
+  named scope, and ``step_module_s``, its sum.
 """
 from __future__ import annotations
 
@@ -32,7 +35,7 @@ import tempfile
 from typing import Dict, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.window"
-SPAN_PREFIXES = ("bench.", "train.", "serve.", "pipeline.", "step.", "gen.")
+SPAN_PREFIXES = ("bench.", "train.", "serve.", "pipeline.", "gen.")
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
     r"|allreduce|allgather|psum")
@@ -58,7 +61,8 @@ class Session:
         jax.profiler.stop_trace()
         return False
 
-    def reduce(self) -> dict:
+    def reduce(self, module: Optional[str] = None,
+               scopes: Optional[Dict[str, str]] = None) -> dict:
         try:
             paths = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"),
                               recursive=True)
@@ -66,7 +70,7 @@ class Session:
                 raise RuntimeError("the profiler wrote no trace")
             from jax.profiler import ProfileData
             return reduce(ProfileData.from_file(paths[0]), self.chips,
-                          self.kernels)
+                          self.kernels, module, scopes)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -191,11 +195,16 @@ def label_segments(spans, lo: int, hi: int):
     return out
 
 
-def reduce(profile, chips: int, kernels: Optional[Dict[str, str]] = None
-           ) -> dict:
+def reduce(profile, chips: int, kernels: Optional[Dict[str, str]] = None,
+           module: Optional[str] = None,
+           scopes: Optional[Dict[str, str]] = None) -> dict:
     """Numbers of one recorded window (see the module's docstring).
     ``kernels`` maps a kernel's name to a regular expression matched
-    against device operation names."""
+    against device operation names; ``module`` and ``scopes`` are the
+    step's module name and ``{instruction: scope}``
+    (``bench.scopes.instruction_scopes``).  A trace with device planes
+    that holds no run of ``module`` in the window is an error: the scopes
+    would read the wrong program."""
     kernels = kernels or {}
     threads = host_spans(profile)
     window = None
@@ -257,7 +266,7 @@ def reduce(profile, chips: int, kernels: Optional[Dict[str, str]] = None
             k += 1
     top_ops = sorted(op_s.items(), key=lambda kv: -kv[1])[:10]
     top_idle = sorted(idle.items(), key=lambda kv: -kv[1])[:10]
-    return {
+    out = {
         "window_s": window_ns / 1e9,
         "busy_s": (sum(busy) / n) / 1e9,
         "device_planes": len(planes),
@@ -270,3 +279,13 @@ def reduce(profile, chips: int, kernels: Optional[Dict[str, str]] = None
             "idle_gaps": [[k, v / 1e9] for k, v in top_idle],
         },
     }
+    if module is not None:
+        from bench.scopes import attribute
+        att = attribute(profile, chips, lo, hi, module, scopes or {})
+        if planes and not att["module_runs"]:
+            raise RuntimeError(
+                f"no run of module {module!r} on the device planes' "
+                "'XLA Modules' lines in the window")
+        out["scope_s"] = att["scope_s"]
+        out["step_module_s"] = att["step_module_s"]
+    return out

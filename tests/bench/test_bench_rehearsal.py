@@ -53,9 +53,17 @@ def test_traced_rehearsal_reports_layers_and_the_window():
     assert dev["window_s"] > 0 and dev["busy_s"] >= 0
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
     names = [n for n, _ in line["breakdown"]["idle_gaps"]]
-    assert "train.epoch" in names or "step.dispatch" in names
+    # the gaps are named by the program's own spans
+    assert "train.epoch" in names and "train.wait" in names
     # host preprocessing is a host clock; device metrics are never
     # reported from a CPU run
     assert line["metrics"]["host_prep_s"]["value"] > 0
     for m in line["metrics"]:
         assert not m.startswith(("idle_share", "mfu"))
+    # the step's scopes are device metrics of the cell, left out here
+    scoped = {"gather_ms.train", "message_ms.train", "aggregate_ms.train",
+              "decoder_loss_ms.train", "optimizer_ms.train",
+              "unscoped_share.train"}
+    assert scoped <= {m["name"] for m in BM["per_layer"]
+                      if cell in m.get("workloads", [cell])}
+    assert not scoped & set(line["metrics"])

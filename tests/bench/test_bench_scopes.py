@@ -56,7 +56,12 @@ def test_instruction_scopes_innermost_backward_remat_and_fusion_roots():
 
 
 def test_innermost_ignores_names_that_are_not_scopes():
+    # every ``kge.<name>`` on the path is a scope, known or not
     assert scopes.innermost("jit(step)/kge.message/kge.other/add") == \
+        "kge.other"
+    # a name that only holds one is not
+    assert scopes.innermost(
+        "jit(step)/kge.message/pjit(kge_score)/xkge.other/kge.a.b/add") == \
         "kge.message"
     assert scopes.innermost("") == scopes.UNSCOPED
 
@@ -134,6 +139,58 @@ def test_attribute_without_the_step_module_reads_zero():
                            scopes.instruction_scopes(HLO)[1])
     assert out["step_module_s"] == 0
     assert set(out["scope_s"]) == set(scopes.SCOPES) | {scopes.UNSCOPED}
+
+
+def test_a_scope_the_known_set_lacks_is_read_as_its_own():
+    """A configuration's own scope (a feature-input projection, say) is
+    attributed to itself, not to ``unscoped``."""
+    text = HLO.replace('op_name="jit(step)/vmap(jvp(kge.gather))/gather"',
+                       'op_name="jit(step)/vmap(jvp(kge.input))/dot_general"')
+    assert "kge.input" not in scopes.SCOPES
+    _, by = scopes.instruction_scopes(text)
+    assert by["fusion.2.remat2"] == "kge.input"
+    out = scopes.attribute(_Profile([_plane(0)]), 1, 0, 150 * MS,
+                           "jit_step", by)
+    assert out["scope_s"]["kge.input"] == pytest.approx(0.002)
+    assert out["scope_s"]["unscoped"] == pytest.approx(0.003)
+    assert "kge.gather" not in out["scope_s"]
+
+
+def _record(planes=1, steps=4):
+    """A traced run's record as ``bench/run.py`` hands it to a reader."""
+    scope_s = {"kge.gather": 0.004, "kge.message": 0.8,
+               "kge.aggregate": 0.6, "kge.decoder_loss": 0.2,
+               "kge.optimizer": 0.0002, "unscoped": 0.04}
+    return {"platform": "tpu", "chips": 1, "peaks": {},
+            "counters": {"steps": steps},
+            "trace": {"device_planes": planes, "scope_s": scope_s,
+                      "step_module_s": sum(scope_s.values())}}
+
+
+READINGS = {"gather_ms.train": 1.0, "message_ms.train": 200.0,
+            "aggregate_ms.train": 150.0, "decoder_loss_ms.train": 50.0,
+            "optimizer_ms.train": 0.05,
+            "unscoped_share.train": 100 * 0.04 / 1.6442}
+
+
+@pytest.mark.parametrize("name", sorted(READINGS))
+def test_scope_reader_reads_a_synthetic_record(name):
+    from bench import run
+    reader = run.load_module("metrics", name)
+    assert reader.read(_record()) == pytest.approx(READINGS[name])
+    # no device plane (a CPU rehearsal), or no trace: nothing to read
+    assert reader.read(_record(planes=0)) is None
+    assert reader.read({**_record(), "trace": None}) is None
+
+
+def test_the_scope_readers_add_up_to_the_step_module():
+    from bench import run
+    rec = _record()
+    ms = sum(run.load_module("metrics", n).read(rec)
+             for n in READINGS if n.endswith("_ms.train"))
+    unscoped = run.load_module("metrics", "unscoped_share.train").read(rec)
+    step_ms = 1000 * rec["trace"]["step_module_s"] / rec["counters"]["steps"]
+    assert ms + unscoped / 100 * step_ms == pytest.approx(step_ms)
 
 
 def test_a_compiled_step_puts_its_heavy_operations_under_scopes():

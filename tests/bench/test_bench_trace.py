@@ -102,6 +102,53 @@ def test_reduction_reads_as_constructed(recorded):
     assert top[0][0] == "all-reduce.3" and len(top) == 3
 
 
+TODAY = {"window_s", "busy_s", "device_planes", "op_s", "kernel_s",
+         "collective_s", "collective_exposed_s", "breakdown"}
+
+
+def _step_plane(spans, module="jit_step(41)"):
+    """A device plane with one run of the step module inside the window:
+    ``fusion.1`` (8 ms) under ``kge.message``, ``sort.2`` (2 ms) under no
+    scope."""
+    s0, _ = spans["serve.step"]
+    ops = [_Event("%fusion.1 = f32[8,75]{1,0} fusion(%p)", s0 + 1 * MS,
+                  s0 + 9 * MS),
+           _Event("%sort.2 = s32[64]{0} sort(%a)", s0 + 9 * MS,
+                  s0 + 11 * MS)]
+    return _Plane("/device:TPU:0", [
+        _Line("XLA Modules", [_Event(module, s0, s0 + 12 * MS)]),
+        _Line("XLA Ops", ops)])
+
+
+def test_reduce_returns_every_key_it_did_and_the_scopes_when_given(
+        recorded):
+    profile, spans = recorded
+    prof = _Profile(list(profile.planes) + [_step_plane(spans)])
+    plain = trace.reduce(prof, chips=1)
+    assert set(plain) == TODAY
+    by = {"fusion.1": "kge.message", "sort.2": "unscoped"}
+    out = trace.reduce(prof, chips=1, module="jit_step", scopes=by)
+    assert set(out) == TODAY | {"scope_s", "step_module_s"}
+    for k in TODAY:
+        assert out[k] == plain[k], k
+    assert out["scope_s"] == pytest.approx({"kge.message": 0.008,
+                                            "unscoped": 0.002})
+    assert out["step_module_s"] == pytest.approx(0.010)
+
+
+def test_a_step_module_missing_from_the_device_planes_is_an_error(
+        recorded):
+    profile, spans = recorded
+    prof = _Profile(list(profile.planes)
+                    + [_step_plane(spans, module="jit_other(41)")])
+    with pytest.raises(RuntimeError, match="jit_step"):
+        trace.reduce(prof, chips=1, module="jit_step",
+                     scopes={"fusion.1": "kge.message"})
+    # with no device plane (a CPU run) there is nothing to check
+    out = trace.reduce(profile, chips=1, module="jit_step", scopes={})
+    assert out["step_module_s"] == 0
+
+
 def test_no_window_span_is_an_error(recorded):
     profile, _ = recorded
     planes = [p for p in profile.planes if not p.name.startswith("/host:")]
